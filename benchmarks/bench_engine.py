@@ -9,10 +9,7 @@ is tracked from this PR onward:
   steady state) per scheme, with the steps-executed / ticks-simulated
   compression ratio;
 * device steps/s and delivered packets/s;
-* the full 10-scheme batched sweep through ``run_schemes`` (one compile);
-* optionally (``--seed-rev REV``) the same cells on the engine of an
-  older git revision, giving an apples-to-apples speedup (the committed
-  JSON records the seed engine of commit v0).
+* the full 10-scheme batched sweep through ``run_schemes`` (one compile).
 
 ``--quick`` is the CI perf guard: it re-times the engine cells and fails
 (non-zero exit) when wall time or event compression regresses by more
@@ -29,17 +26,14 @@ refresh it.
 
 Usage::
 
-    PYTHONPATH=src python -m benchmarks.bench_engine [--seed-rev fc87b58]
+    PYTHONPATH=src python -m benchmarks.bench_engine
     PYTHONPATH=src python -m benchmarks.bench_engine --quick
 """
 from __future__ import annotations
 
 import argparse
-import importlib.util
 import json
-import subprocess
 import sys
-import tempfile
 import time
 from pathlib import Path
 
@@ -168,18 +162,17 @@ def _time_run(run_fn, spec, warm_reps: int = 3, **kw):
     return res, cold, warm
 
 
-def _engine_cells(engine, spec_for, schemes, *, reference_too: bool,
-                  label: str):
+def _engine_cells(spec_for, schemes):
+    from repro.net.sim import engine as E
     from repro.net.sim.types import SCHEME_NAMES
     out = {}
     for scheme in schemes:
         spec = spec_for(scheme)
-        cell = {}
-        res, cold, warm = _time_run(engine.run, spec)
-        cell.update(
+        res, cold, warm = _time_run(E.run, spec)
+        cell = dict(
             wall_s_cold=round(cold, 2), wall_s_warm=round(warm, 2),
-            steps_executed=int(getattr(res, "steps_executed", -1)),
-            ticks_simulated=int(getattr(res, "ticks_simulated", -1)),
+            steps_executed=int(res.steps_executed),
+            ticks_simulated=int(res.ticks_simulated),
             delivered_pkts=int(res.delivered.sum()),
             done_frac=float(res.done.mean()),
         )
@@ -189,30 +182,12 @@ def _engine_cells(engine, spec_for, schemes, *, reference_too: bool,
             cell["steps_per_s"] = round(cell["steps_executed"] / warm, 1)
             cell["delivered_pkts_per_s"] = round(
                 cell["delivered_pkts"] / warm, 1)
-        if reference_too:
-            _, _, ref_warm = _time_run(engine.run, spec, reference=True)
-            cell["wall_s_dense_warm"] = round(ref_warm, 2)
-            cell["speedup_vs_dense"] = round(ref_warm / warm, 2)
+        _, _, ref_warm = _time_run(E.run, spec, reference=True)
+        cell["wall_s_dense_warm"] = round(ref_warm, 2)
+        cell["speedup_vs_dense"] = round(ref_warm / warm, 2)
         out[SCHEME_NAMES[scheme]] = cell
-        print(f"  [{label}] {SCHEME_NAMES[scheme]}: {cell}", flush=True)
+        print(f"  [engine] {SCHEME_NAMES[scheme]}: {cell}", flush=True)
     return out
-
-
-def _load_rev_engine(rev: str):
-    """Materialize ``src/repro/net/sim/engine.py`` of ``rev`` as a module
-    (against the *current* types/build/spritz — their engine-facing API is
-    backwards compatible)."""
-    src = subprocess.check_output(
-        ["git", "show", f"{rev}:src/repro/net/sim/engine.py"],
-        cwd=REPO_ROOT, text=True)
-    with tempfile.NamedTemporaryFile("w", suffix="_engine.py",
-                                     delete=False) as f:
-        f.write(src)
-        path = f.name
-    mspec = importlib.util.spec_from_file_location(f"engine_{rev}", path)
-    mod = importlib.util.module_from_spec(mspec)
-    mspec.loader.exec_module(mod)
-    return mod
 
 
 def _quick_guard(out_dir: Path):
@@ -289,13 +264,12 @@ def _quick_guard(out_dir: Path):
 
 
 def run(scale: str = "small", out_dir: Path = Path("results/bench"),
-        seed_rev: str | None = None, quick: bool = False):
+        quick: bool = False):
     if scale == "paper":
         return _paper_scale(out_dir)
     if quick:
         return _quick_guard(out_dir)
     from benchmarks.common import ALL_SCHEMES, run_schemes
-    from repro.net.sim import engine as E
 
     topo, flows, spec_for, schemes = _quick_cell()
     print(f"[engine] quick cell: {topo.name}, {len(flows)} flows x 512 pkts",
@@ -307,8 +281,7 @@ def run(scale: str = "small", out_dir: Path = Path("results/bench"),
             "n_flows": len(flows), "size_pkts": 512,
             "n_ticks": 1 << 17, "n_pkt_cap": 1 << 17,
         },
-        "engine": _engine_cells(E, spec_for, schemes, reference_too=True,
-                                label="current"),
+        "engine": _engine_cells(spec_for, schemes),
         "compression_probe": _compression_probe(),
     }
     print(f"  [compression_probe] {report['compression_probe']}", flush=True)
@@ -323,20 +296,6 @@ def run(scale: str = "small", out_dir: Path = Path("results/bench"),
         "note": "one compile + one vmapped while_loop for all schemes",
     }
     print(f"  [batched] {report['batched_sweep']}", flush=True)
-
-    if seed_rev:
-        old = _load_rev_engine(seed_rev)
-        report["baseline"] = {
-            "rev": seed_rev,
-            "engine": _engine_cells(old, spec_for, schemes,
-                                    reference_too=False,
-                                    label=f"rev {seed_rev}"),
-        }
-        for name, cell in report["engine"].items():
-            base = report["baseline"]["engine"].get(name, {})
-            if base.get("wall_s_warm"):
-                cell["speedup_vs_baseline"] = round(
-                    base["wall_s_warm"] / cell["wall_s_warm"], 2)
 
     out = REPO_ROOT / "BENCH_engine.json"
     if out.is_file():
@@ -356,8 +315,6 @@ def run(scale: str = "small", out_dir: Path = Path("results/bench"),
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
-    ap.add_argument("--seed-rev", default=None,
-                    help="git rev whose engine to benchmark as baseline")
     ap.add_argument("--quick", action="store_true",
                     help="CI guard: compare against BENCH_engine.json and "
                          "fail on >25%% wall-time/compression regression")
@@ -366,5 +323,7 @@ if __name__ == "__main__":
                          "occupancy-bounded engine (merges the 'paper' "
                          "key of BENCH_engine.json; never gated)")
     args = ap.parse_args()
-    run(scale=args.scale, seed_rev=args.seed_rev, quick=args.quick)
+    from repro import compile_cache
+    compile_cache.enable()
+    run(scale=args.scale, quick=args.quick)
     sys.exit(0)

@@ -64,6 +64,8 @@ def bench_cli(run, argv=None, **fixed):
                          f"(known: {','.join(REG.names())})")
     ap.add_argument("--out", default="results/bench")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
     scale = args.scale or ("full" if args.full else "small")
     kw = dict(schemes=scheme_codes(args.schemes), quick=args.quick, **fixed)
     accepted = inspect.signature(run).parameters

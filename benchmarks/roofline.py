@@ -134,6 +134,8 @@ def main():
     ap.add_argument("--table", action="store_true",
                     help="print markdown table from saved results")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     out_dir = Path(args.out)
 
     from repro import configs as C

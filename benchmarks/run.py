@@ -74,6 +74,8 @@ def main() -> None:
                          "to every suite that takes a scheme set")
     ap.add_argument("--out", default="results/bench")
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.enable()
     out = Path(args.out)
     quick = args.scale == "quick"
     scale = "small" if quick else args.scale

@@ -65,6 +65,8 @@ def main(argv=None) -> int:
                                   "tables from the registered cells")
 
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
 
     if args.cmd == "list":
         for c in matrix.cells(tier=args.tier, bench=args.bench):

@@ -1,12 +1,15 @@
 """Content hashing for experiment-cell results (DESIGN.md §13).
 
-A cell's cached result is keyed by ``sha256(cell spec JSON + source
-tree digest)``: re-running an unchanged cell on an unchanged source
-tree is a cache hit, and *any* edit to the cell definition or to the
-git-tracked simulator/benchmark sources invalidates every affected
-cell.  The source digest hashes file *contents* (not git index blobs),
-so unstaged edits invalidate too; outside a git checkout it falls back
-to globbing the same directories.
+A cell's cached result is keyed by ``sha256(cell spec JSON + device
+descriptor + source tree digest)``: re-running an unchanged cell on an
+unchanged source tree and the same devices is a cache hit, and *any*
+edit to the cell definition or to the git-tracked simulator/benchmark
+sources invalidates every affected cell.  The device descriptor
+(platform, device kind, device count) keeps a result computed on one
+backend from ever being served to a run on another — a CPU result never
+stands in for a TPU run.  The source digest hashes file *contents* (not
+git index blobs), so unstaged edits invalidate too; outside a git
+checkout it falls back to globbing the same directories.
 """
 from __future__ import annotations
 
@@ -62,10 +65,19 @@ def tree_digest(root: Path | None = None) -> str:
     return h.hexdigest()
 
 
+def device_key() -> dict:
+    """The devices a result was computed on, as JAX reports them."""
+    import jax
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": jax.device_count()}
+
+
 def cell_hash(cell, root: Path | None = None) -> str:
-    """Content hash of a cell: canonical spec JSON + source tree digest."""
-    payload = json.dumps(cell.to_json(), sort_keys=True,
-                         separators=(",", ":"))
+    """Content hash of a cell: canonical spec JSON + device descriptor +
+    source tree digest."""
+    payload = json.dumps({"spec": cell.to_json(), "device": device_key()},
+                         sort_keys=True, separators=(",", ":"))
     h = hashlib.sha256()
     h.update(payload.encode())
     h.update(tree_digest(root).encode())

@@ -2,8 +2,9 @@
 
 Dispatches selected cells through the packet / flow / host executors,
 emits one normalized JSON per cell under ``results/exp/`` keyed by the
-content hash of ``(cell spec, git-tracked sources)`` — unchanged cells
-are skipped on re-run — and evaluates ratio/counter guards.  Any guard
+content hash of ``(cell spec, devices, git-tracked sources)`` — unchanged
+cells on the same devices are skipped on re-run; each JSON records the
+``device`` it ran on — and evaluates ratio/counter guards.  Any guard
 breach makes :func:`run` report failure (the CLI exits non-zero).
 """
 from __future__ import annotations
@@ -15,7 +16,7 @@ from pathlib import Path
 
 from repro.exp import guards as G
 from repro.exp import matrix
-from repro.exp.hashing import cell_hash, repo_root
+from repro.exp.hashing import cell_hash, device_key, repo_root
 from repro.exp.spec import RESULT_SCHEMA_VERSION, validate_result
 
 DEFAULT_OUT = Path("results/exp")
@@ -121,6 +122,7 @@ def run_cell(cell, out: Path = DEFAULT_OUT, force: bool = False,
         "schema": RESULT_SCHEMA_VERSION,
         "cell_id": cell.cell_id,
         "hash": h,
+        "device": device_key(),
         "spec": cell.to_json(),
         "schemes_run": schemes,
         "rows": rows,

@@ -183,6 +183,11 @@ def validate_result(obj: dict) -> list[str]:
         errs.append(f"schema != {RESULT_SCHEMA_VERSION}")
     need("cell_id", str)
     need("hash", str)
+    device = need("device", dict)
+    if device is not None:
+        for k in ("platform", "kind", "count"):
+            if k not in device:
+                errs.append(f"device missing {k!r}")
     spec = need("spec", dict)
     if spec is not None:
         for k in ("engine", "topology", "workload", "schemes", "seeds",

@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU —
-the kernels are written for TPU BlockSpec tiling and validated here via the
-interpreter against the ``ref`` oracles.
+``interpret`` defaults to False on a TPU backend — the kernels are written
+for TPU BlockSpec tiling and always compile through Mosaic there — and to
+True elsewhere, where the interpreter runs the kernel bodies so the CPU
+tests can check them against the ``ref`` oracles.
 """
 from __future__ import annotations
 
@@ -14,6 +15,7 @@ from repro.kernels.rwkv6_chunked import rwkv6_chunked as _rwkv6
 
 
 def _default_interpret() -> bool:
+    """Interpret mode exactly when the default backend is not a TPU."""
     return jax.default_backend() != "tpu"
 
 
@@ -42,7 +44,7 @@ def rwkv6_chunked(r, k, v, w, u, wkv0, *, chunk=64, interpret=None):
 
 
 def red_ecn(eport, rank, enq, unif, q_tail, t, *, qsize, kmin, kmax,
-            n_ports, block_n=512, interpret=None):
+            n_ports, block_n=1024, interpret=None):
     from repro.kernels.red_ecn import red_ecn as _red
     if interpret is None:
         interpret = _default_interpret()

@@ -5,6 +5,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.red_ecn import mark_scale
+from repro.net.policies.base import prefix_sum
+
 
 def mha_reference(q, k, v, *, causal: bool = True, sliding_window: int = 0,
                   q_offset: int = 0):
@@ -32,7 +35,7 @@ def spritz_select_reference(w, u, buf_front, packet_count, *,
                             explore_threshold: int):
     """Mirror of repro.core.spritz.send_logic's selection core."""
     w = w.astype(jnp.float32)
-    csum = jnp.cumsum(w, axis=1)
+    csum = prefix_sum(w)
     total = csum[:, -1]
     uu = u * jnp.maximum(total, 1e-30)
     sampled = jnp.minimum(
@@ -53,8 +56,8 @@ def red_ecn_reference(eport, rank, enq, unif, q_tail, t, *, qsize, kmin,
     occ = jnp.maximum(tail - t, 0) + rank
     trim = enq & (occ >= qsize)
     accept = enq & ~trim
-    pr = jnp.clip((occ.astype(jnp.float32) - kmin) /
-                  max(kmax - kmin, 1e-9), 0.0, 1.0)
+    pr = jnp.clip((occ.astype(jnp.float32) - kmin) * mark_scale(kmin, kmax),
+                  0.0, 1.0)
     mark = accept & (unif < pr)
     slot = jnp.maximum(tail, t) + rank + 1
     return occ, trim, mark, jnp.where(accept, slot, 0)
@@ -72,12 +75,11 @@ def tick_rank_reference(port, *, n_ports: int):
 
 
 def flow_agg_reference(rows, pflow, *, n_flows: int):
-    """Oracle for kernels.flow_agg (mirrors engine.py flow_sums_fn's
-    one-hot GEMM): ``out[k, f] = sum(rows[k, pflow == f])``."""
-    oh = (pflow[:, None]
-          == jnp.arange(n_flows, dtype=jnp.int32)[None, :]
-          ).astype(jnp.float32)
-    return (rows.astype(jnp.float32) @ oh).astype(jnp.int32)
+    """Oracle for kernels.flow_agg: ``out[k, f] = sum(rows[k, pflow ==
+    f])`` as an integer segment sum (exact on every backend)."""
+    pf = jnp.where((pflow < 0) | (pflow >= n_flows), n_flows, pflow)
+    return jnp.zeros((n_flows + 1, rows.shape[0]), jnp.int32).at[pf].add(
+        rows.T.astype(jnp.int32))[:n_flows].T
 
 
 def rwkv6_reference(r, k, v, w, u, wkv0):

@@ -1,15 +1,19 @@
 """Spritz send-logic hot loop as a Pallas TPU kernel.
 
 Per packet tick, every active flow runs Algorithm 1: weighted sampling over
-its path-weight row (cumulative sum + threshold search) fused with the
+its path-weight row (prefix sum + threshold count) fused with the
 explore-counter and buffer-front selection.  At datacenter scale this runs
 per endpoint per ~80 ns packet slot, so the simulator treats it as its
 perf-critical inner kernel (the analogue of the paper's NIC/host datapath).
 
-Tiling: flows x paths rows live in VMEM blocks of (block_f, P); the weighted
-choice is a row cumsum + compare-reduce — VPU-friendly, no MXU needed.
-Validated against ``ref.spritz_select_reference`` (also used by the pure-jnp
-simulator path) in interpret mode.
+Layout: the wrapper transposes the weights to [P, F] so flows lie along
+lanes and paths along sublanes; the prefix sum is a static unrolled scan
+over the P rows (``csum_j = csum_{j-1} + w_j``) — the same f32 add order
+as ``policies.base.prefix_sum``, which the pure-jnp sampler uses, so the
+kernel and the jnp path draw bit-identical paths on every backend.  Per-
+flow vectors travel as lane-dense [1, F] rows; the boolean output is an
+int32 row, cast back in the wrapper.  Validated against
+``ref.spritz_select_reference`` in interpret mode.
 """
 from __future__ import annotations
 
@@ -21,13 +25,15 @@ from jax.experimental import pallas as pl
 
 
 def _select_kernel(w_ref, u_ref, front_ref, count_ref, ev_ref, newcnt_ref,
-                   used_buf_ref, *, explore_threshold):
-    w = w_ref[...].astype(jnp.float32)            # [bf, P]
-    csum = jnp.cumsum(w, axis=1)
-    total = csum[:, -1:]
-    u = u_ref[...] * jnp.maximum(total[:, 0], 1e-30)
-    sampled = jnp.sum((csum < u[:, None]).astype(jnp.int32), axis=1)
-    sampled = jnp.minimum(sampled, w.shape[1] - 1)
+                   used_buf_ref, *, explore_threshold, n_paths):
+    csum = [w_ref[0:1, :]]                                     # [1, bf] each
+    for p in range(1, n_paths):
+        csum.append(csum[-1] + w_ref[p:p + 1, :])
+    u = u_ref[...] * jnp.maximum(csum[-1], 1e-30)
+    sampled = jnp.zeros(u.shape, jnp.int32)
+    for c in csum:
+        sampled += (c < u).astype(jnp.int32)
+    sampled = jnp.minimum(sampled, n_paths - 1)
 
     count = count_ref[...]
     front = front_ref[...]
@@ -35,7 +41,7 @@ def _select_kernel(w_ref, u_ref, front_ref, count_ref, ev_ref, newcnt_ref,
     use_buffer = (~explore) & (front >= 0)
     ev_ref[...] = jnp.where(use_buffer, front, sampled)
     newcnt_ref[...] = jnp.where(explore, 0, count + 1)
-    used_buf_ref[...] = use_buffer
+    used_buf_ref[...] = use_buffer.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("explore_threshold", "block_f",
@@ -61,33 +67,23 @@ def spritz_select(w, u, buf_front, packet_count, *, explore_threshold: int,
         raise ValueError(
             f"buf_front/packet_count must be int32, got "
             f"{buf_front.dtype}/{packet_count.dtype}")
-    block_f = min(block_f, F)
-    padF = (F + block_f - 1) // block_f * block_f
-    if padF != F:
-        w = jnp.pad(w, ((0, padF - F), (0, 0)))
-        u = jnp.pad(u, (0, padF - F))
-        buf_front = jnp.pad(buf_front, (0, padF - F), constant_values=-1)
-        packet_count = jnp.pad(packet_count, (0, padF - F))
-    grid = (padF // block_f,)
+    # lane-aligned tile: block_f rounded up to a multiple of 128
+    block_f = pl.cdiv(min(block_f, F), 128) * 128
+    padF = pl.cdiv(F, block_f) * block_f
+
+    def row(x, fill=0):
+        return jnp.pad(x, (0, padF - F), constant_values=fill)[None, :]
+
+    wt = jnp.pad(w.astype(jnp.float32), ((0, padF - F), (0, 0))).T  # [P, padF]
+    lane = pl.BlockSpec((1, block_f), lambda i: (0, i))
     ev, newcnt, used = pl.pallas_call(
-        functools.partial(_select_kernel, explore_threshold=explore_threshold),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_f, P), lambda i: (i, 0)),
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-            pl.BlockSpec((block_f,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((padF,), jnp.int32),
-            jax.ShapeDtypeStruct((padF,), jnp.int32),
-            jax.ShapeDtypeStruct((padF,), jnp.bool_),
-        ],
+        functools.partial(_select_kernel, explore_threshold=explore_threshold,
+                          n_paths=P),
+        grid=(padF // block_f,),
+        in_specs=[pl.BlockSpec((P, block_f), lambda i: (0, i)),
+                  lane, lane, lane],
+        out_specs=[lane, lane, lane],
+        out_shape=[jax.ShapeDtypeStruct((1, padF), jnp.int32)] * 3,
         interpret=interpret,
-    )(w, u, buf_front, packet_count)
-    return ev[:F], newcnt[:F], used[:F]
+    )(wt, row(u.astype(jnp.float32)), row(buf_front, -1), row(packet_count))
+    return ev[0, :F], newcnt[0, :F], used[0, :F].astype(bool)

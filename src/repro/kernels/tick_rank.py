@@ -5,15 +5,20 @@ tick, its arrival rank among same-tick arrivals at the same egress port
 (engine.py ``_enqueue_rank``): the analytic FIFO then departs the rank-k
 accept at ``max(tail, t) + k + 1``.  At paper scale the engine's one-hot
 rank histogram ([M, n_ports] cells) blows the one-hot budget and the
-argsort fallback serializes; this kernel streams the compacted enqueue
-set in blocks and carries a per-port running count across blocks in VMEM
-scratch — the segmented scatter-rank with O(M * n_ports / block) work and
-no [M, n_ports] materialization.
+argsort fallback serializes; this kernel counts, for every entry ``i``,
+the earlier entries ``j < i`` with the same port as a pairwise compare
+over [block, block] tiles:
 
-Grid is 1-D over packet blocks and *must* execute sequentially (TPU grids
-do; the interpreter does): block i reads the counts accumulated by blocks
-< i, ranks its packets with an in-block one-hot cumsum, then bumps the
-counts.  f32 count arithmetic is exact (counts < 2^24).
+    rank[i] = sum_{j < i} (port[j] == port[i])
+
+O(M^2 / 2) exact integer work, independent of ``n_ports`` — no per-port
+scratch, no one-hot over ports, no in-kernel scan or gather.
+
+Grid is (i-block, j-block): i-blocks lay the ranked entries along lanes
+(output block ``[1, block]``), j-blocks lay the compared entries along
+sublanes (input ``[block, 1]``); j is the reduction axis (innermost,
+sequential), and tiles with ``j > i`` are skipped.  Per-tile counts are
+at most ``block`` (< 2^24), so the f32 sublane reduction is exact.
 
 Entries outside ``[0, n_ports)`` (the compaction sentinel ``n_ports``,
 or -1 pads) share one overflow bucket; their ranks are well-defined but
@@ -27,28 +32,27 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
-def _tick_rank_kernel(port_ref, rank_ref, counts_ref, *, n_ports):
+def _tick_rank_kernel(pj_ref, pi_ref, rank_ref, *, block):
     i = pl.program_id(0)
+    j = pl.program_id(1)
 
-    @pl.when(i == 0)
+    @pl.when(j == 0)
     def _init():
-        counts_ref[...] = jnp.zeros_like(counts_ref)
+        rank_ref[...] = jnp.zeros_like(rank_ref)
 
-    port = port_ref[...]                                       # [bm] i32
-    # out-of-range entries (sentinel n_ports, -1 pads) -> overflow bucket
-    port_c = jnp.where((port < 0) | (port >= n_ports), n_ports, port)
-    oh = (port_c[:, None]
-          == jnp.arange(n_ports + 1, dtype=jnp.int32)[None, :]
-          ).astype(jnp.float32)                                # [bm, np+1]
-    counts = counts_ref[...]                                   # [np+1] f32
-    prev = oh @ counts                                         # [bm]
-    within = jnp.cumsum(oh, axis=0) * oh
-    wrank = jnp.sum(within, axis=1) - 1.0                      # [bm] 0-based
-    rank_ref[...] = (prev + wrank).astype(jnp.int32)
-    counts_ref[...] = counts + jnp.sum(oh, axis=0)
+    @pl.when(j <= i)
+    def _count():
+        pj = pj_ref[...]                                       # [bj, 1]
+        pi = pi_ref[...]                                       # [1, bi]
+        gj = j * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0)
+        gi = i * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 1)
+        hit = (pj == pi) & (gj < gi)                           # [bj, bi]
+        rank_ref[...] += jnp.sum(hit.astype(jnp.float32), axis=0,
+                                 keepdims=True).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("n_ports", "block_m",
@@ -65,20 +69,21 @@ def tick_rank(port, *, n_ports: int, block_m: int = 512,
     if n_ports < 1:
         raise ValueError(f"n_ports must be >= 1, got {n_ports}")
     M = port.shape[0]
-    block_m = min(block_m, M)
-    padM = (M + block_m - 1) // block_m * block_m
-    if padM != M:
-        # pads land in the overflow bucket *after* every real entry, so
-        # real ranks are unchanged
-        port = jnp.pad(port, (0, padM - M), constant_values=-1)
-    grid = (padM // block_m,)
+    # lane-aligned tile: block_m rounded up to a multiple of 128
+    block = pl.cdiv(min(block_m, M), 128) * 128
+    padM = pl.cdiv(M, block) * block
+    # out-of-range entries (sentinel n_ports, -1 pads) -> overflow bucket;
+    # pads land there *after* every real entry, so real ranks are unchanged
+    port_c = jnp.where((port < 0) | (port >= n_ports), n_ports, port)
+    port_c = jnp.pad(port_c, (0, padM - M), constant_values=n_ports)
+    nb = padM // block
     rank = pl.pallas_call(
-        functools.partial(_tick_rank_kernel, n_ports=n_ports),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_m,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((block_m,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((padM,), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((n_ports + 1,), jnp.float32)],
+        functools.partial(_tick_rank_kernel, block=block),
+        grid=(nb, nb),
+        in_specs=[pl.BlockSpec((block, 1), lambda i, j: (j, 0)),
+                  pl.BlockSpec((1, block), lambda i, j: (0, i))],
+        out_specs=pl.BlockSpec((1, block), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, padM), jnp.int32),
         interpret=interpret,
-    )(port)
-    return rank[:M]
+    )(port_c.reshape(padM, 1), port_c.reshape(1, padM))
+    return rank[0, :M]

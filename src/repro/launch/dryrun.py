@@ -214,8 +214,7 @@ def run_cell(arch: str, shape, multi_pod: bool, out_dir: Path,
                     v = getattr(ma, f, None)
                     if v is not None:
                         rec[f] = int(v)
-            from repro.launch.hlo_analysis import xla_cost_analysis
-            ca = xla_cost_analysis(compiled)
+            ca = compiled.cost_analysis() or {}
             rec["flops"] = float(ca.get("flops", -1))
             rec["bytes_accessed"] = float(ca.get("bytes accessed", -1))
             text = compiled.as_text()

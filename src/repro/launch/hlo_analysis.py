@@ -39,14 +39,6 @@ COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute")
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """Version-tolerant ``compiled.cost_analysis()``: jax <= 0.4.x returns
-    a one-dict-per-partition list, newer jax returns the dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
 # ops that move no data / are layout-only views
 _FREE_OPS = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
              "after-all", "partition-id", "replica-id", "iota"}

@@ -14,14 +14,7 @@ import numpy as np
 from repro.models.common import ModelCfg, init_mlp, apply_mlp
 from repro.models import common as _common
 
-try:  # modern API (jax >= 0.8)
-    from jax import shard_map
-except ImportError:  # jax 0.4.x: experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_04x
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_04x(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
+from jax import shard_map
 
 
 def init_moe(key, cfg: ModelCfg):

@@ -148,6 +148,19 @@ class PolicyDef:
     doc: str = ""
 
 
+def prefix_sum(w: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum over the last axis as a sequential chain of
+    f32 adds (``c_j = c_{j-1} + w_j``).  ``jnp.cumsum`` lowers to a
+    different association per backend, so the sampler fixes the order
+    here; the Pallas ``spritz_select`` kernel adds in the same order,
+    which keeps the kernel and jnp paths bit-identical on every
+    backend (DESIGN.md §14)."""
+    cols = [w[..., 0]]
+    for j in range(1, w.shape[-1]):
+        cols.append(cols[-1] + w[..., j])
+    return jnp.stack(cols, axis=-1)
+
+
 def weighted_sample_rows(rng: jax.Array, w: jnp.ndarray) -> jnp.ndarray:
     """Per-row weighted index sample from ONE shared uniform draw.
 
@@ -158,7 +171,7 @@ def weighted_sample_rows(rng: jax.Array, w: jnp.ndarray) -> jnp.ndarray:
     branch consumes the tick key identically (DESIGN.md §5).
     Rows with all-zero weights fall back to index 0.
     """
-    csum = jnp.cumsum(w, axis=-1)
+    csum = prefix_sum(w)
     u = jax.random.uniform(rng, (w.shape[0], 1)) * jnp.maximum(
         csum[:, -1:], 1e-30)
     return jnp.minimum(jnp.sum((csum < u).astype(jnp.int32), -1),

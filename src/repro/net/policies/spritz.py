@@ -64,7 +64,7 @@ class SpritzConfig(NamedTuple):
     use_kernels: bool = False       # route Algorithm 1's selection core
     #   through kernels.spritz_select (DESIGN.md §14); bit-identical to
     #   the jnp path — both consume ONE uniform(rng, (F, 1)) draw and run
-    #   the same cumsum/compare math per row
+    #   the same prefix-sum (policies.base.prefix_sum)/compare math per row
 
 
 class SpritzState(NamedTuple):

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops as KOPS
+from repro.kernels.red_ecn import mark_scale
 from repro.net.policies import base as PB
 from repro.net.policies import registry as REG
 from repro.net.sim.types import (FB_ACK_ECN, FB_ACK_OK, FB_NACK, FB_NONE,
@@ -276,9 +277,12 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
     def flow_sums_fn(pflow):
         """Per-flow sums as ONE one-hot GEMM instead of per-mask scatters
         (XLA CPU scatter walks updates serially; the [K,N]x[N,F] product
-        vectorizes).  Counts are < 2^24, so f32 accumulation is exact.
-        Beyond the one-hot cell budget (paper-scale F x N) fall back to
-        one multi-column segment scatter-add; with ``use_kernels`` the
+        vectorizes).  The GEMM runs at ``Precision.HIGHEST``: a TPU's
+        default precision multiplies in bf16, exact for integers only up
+        to 256, and a delivered PSN exceeds that; at full f32 precision
+        values and sums < 2^24 are exact on every backend.  Beyond the
+        one-hot cell budget (paper-scale F x N) fall back to one
+        multi-column segment scatter-add; with ``use_kernels`` the
         Pallas flow_agg kernel streams the same GEMM in [K, block] tiles
         without materializing [N, F] — exact either way."""
         if use_kernels:
@@ -291,8 +295,9 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
                        ).astype(jnp.float32)                 # [N, F]
 
             def flow_sums(rows):                             # [K, N] -> [K, F]
-                return (rows.astype(jnp.float32)
-                        @ flow_oh).astype(jnp.int32)
+                return jnp.dot(rows.astype(jnp.float32), flow_oh,
+                               precision=jax.lax.Precision.HIGHEST
+                               ).astype(jnp.int32)
         else:
             def flow_sums(rows):
                 # one scatter pass over all K columns (integer adds are
@@ -594,7 +599,7 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
             trim = valid & (occ_at >= spec.qsize)
             # RED / ECN marking probability between kmin..kmax
             pr = jnp.clip((occ_at.astype(jnp.float32) - spec.kmin)
-                          / max(spec.kmax - spec.kmin, 1e-9), 0.0, 1.0)
+                          * mark_scale(spec.kmin, spec.kmax), 0.0, 1.0)
             mark = (valid & ~trim) & (jax.random.uniform(k_mark, (M,)) < pr)
             if HAS_RATE:
                 # service slots stride by the interval: rank-k accept
@@ -652,8 +657,8 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
         one-hot port indicators (cumsum of scatter contributions) read back
         at each packet's own port.  Large fabrics: stable argsort over the
         M-compacted set (still ~N/M cheaper than the old table-wide sort).
-        With ``use_kernels`` the Pallas tick_rank kernel streams the same
-        segmented rank in blocks with a per-port VMEM count carry.  All
+        With ``use_kernels`` the Pallas tick_rank kernel counts earlier
+        same-port entries pairwise over [block, block] tiles.  All
         paths produce the identical rank for valid entries: position among
         this tick's enqueues of the same port, ordered by packet-table
         index (invalid/sentinel entries are masked by callers).
@@ -869,16 +874,15 @@ def _runner(spec: SimSpec, *, dense: bool, batched: bool, shard: int = 0):
                 # device runs the identical vmapped driver over its lane
                 # slice, so per-lane results are bit-identical to the
                 # unsharded (and solo) runs — lanes never communicate.
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import Mesh
                 from jax.sharding import PartitionSpec as PS
                 mesh = Mesh(np.asarray(jax.devices()[:shard]), ("lanes",))
-                vloop = shard_map(
+                vloop = jax.shard_map(
                     vloop, mesh=mesh,
                     in_specs=(PS("lanes"), PS(), PS("lanes"), PS("lanes"),
                               PS(), PS("lanes")),
                     out_specs=(PS("lanes"), PS("lanes"), PS("lanes")),
-                    check_rep=False)
+                    check_vma=False)
             runner = jax.jit(vloop, donate_argnums=(0,))
         else:
             runner = jax.jit(lambda c, w, t0, s0, lim:
@@ -1088,6 +1092,31 @@ def run_batch(spec: SimSpec | Sequence[SimSpec],
     with the identical spec/schemes/seeds.  Segmenting is bit-identical
     to one unsegmented call, exactly as in :func:`run`.
     """
+    base, n_lanes, n_shard, args = _batch_inputs(
+        spec, schemes, seeds, stop_flows=stop_flows, shard=shard,
+        until_tick=until_tick, resume=resume)
+    runner = _runner(base, dense=reference, batched=True, shard=n_shard)
+    with warnings.catch_warnings():
+        # donation is a no-op on CPU; the advisory warning is noise there
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        carry, t, steps = runner(*args)
+    out, states = [], []
+    for i in range(n_lanes):  # pad lanes (lane-0 replicas) are dropped
+        lane_carry = jax.tree.map(lambda x: x[i], carry)
+        out.append(_result(lane_carry, t[i], steps[i]))
+        if return_carry:
+            states.append(_carry_state(lane_carry))
+    if return_carry:
+        return out, states
+    return out
+
+
+def _batch_inputs(spec, schemes, seeds, *, stop_flows=None, shard=None,
+                  until_tick=None, resume=None):
+    """``run_batch``'s lane assembly: returns ``(base spec, n_lanes,
+    n_shard, args)`` where ``args`` is the batched runner's argument
+    tuple, its lane axis padded to a multiple of ``n_shard`` devices."""
     if isinstance(spec, SimSpec):
         if schemes is None:
             schemes = [spec.scheme]
@@ -1146,24 +1175,9 @@ def run_batch(spec: SimSpec | Sequence[SimSpec],
     carry0 = jax.tree.map(lambda *xs: jnp.stack(xs), *carries)
     watch = jnp.asarray(_watch_mask(base, stop_flows))
     limit = base.n_ticks if until_tick is None else int(until_tick)
-
-    runner = _runner(base, dense=reference, batched=True, shard=n_shard)
-    with warnings.catch_warnings():
-        # donation is a no-op on CPU; the advisory warning is noise there
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        carry, t, steps = runner(carry0, watch, jnp.asarray(t0),
-                                 jnp.asarray(steps0), jnp.int32(limit),
-                                 lanes)
-    out, states = [], []
-    for i in range(n_lanes):  # pad lanes (lane-0 replicas) are dropped
-        lane_carry = jax.tree.map(lambda x: x[i], carry)
-        out.append(_result(lane_carry, t[i], steps[i]))
-        if return_carry:
-            states.append(_carry_state(lane_carry))
-    if return_carry:
-        return out, states
-    return out
+    return base, n_lanes, n_shard, (carry0, watch, jnp.asarray(t0),
+                                    jnp.asarray(steps0), jnp.int32(limit),
+                                    lanes)
 
 
 def batch_lanes(schemes: Sequence[int | str], seeds: Sequence[int]

@@ -113,7 +113,8 @@ def test_cell_hash_covers_spec_and_tree(monkeypatch):
 
 
 def test_result_schema_validator_rejects_drift():
-    ok = {"schema": 1, "cell_id": "x", "hash": "h", "spec": {
+    ok = {"schema": 1, "cell_id": "x", "hash": "h",
+          "device": {"platform": "cpu", "kind": "cpu", "count": 1}, "spec": {
         "engine": "packet", "topology": "d", "workload": "w",
         "schemes": [], "seeds": [0], "tiers": ["ci"], "guards": []},
         "rows": [{"scheme": "ecmp", "seed": 0}], "guards": [],
@@ -125,6 +126,10 @@ def test_result_schema_validator_rejects_drift():
     assert validate_result(bad)
     assert validate_result({**ok, "rows": [{"seed": 0}]})  # scheme missing
     assert validate_result({**ok, "guards": [{"ok": True}]})  # desc missing
+    no_dev = dict(ok)
+    del no_dev["device"]
+    assert validate_result(no_dev)
+    assert validate_result({**ok, "device": {"platform": "tpu"}})
 
 
 # --------------------------------------------- runner: cache + guards
@@ -163,6 +168,56 @@ def test_cache_hit_then_invalidation(probe_run, monkeypatch):
     stored = json.loads(path.read_text())
     cell = matrix.CELLS["engine.dragonfly.probe.smoke"]
     assert hashing.cell_hash(cell) != stored["hash"]
+
+
+def test_device_change_forces_cache_miss(probe_run, monkeypatch):
+    """A result is keyed on the devices it ran on: the stored JSON names
+    them, and the same cell on another device descriptor (say the CPU
+    result asked for by a TPU run) misses the cache and re-executes."""
+    out, _ = probe_run
+    cell = matrix.CELLS["engine.dragonfly.probe.smoke"]
+    stored = json.loads((out / f"{cell.cell_id}.json").read_text())
+    assert stored["device"] == hashing.device_key()
+    assert stored["device"]["platform"] == "cpu"
+    calls = []
+
+    def execute(c, schemes, verbose):
+        calls.append(c.cell_id)
+        return stored["rows"]
+
+    monkeypatch.setattr(runner, "_execute", execute)
+    tpu = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(hashing, "device_key", lambda: tpu)
+    monkeypatch.setattr(runner, "device_key", lambda: tpu)
+    assert hashing.cell_hash(cell) != stored["hash"]
+    other = out / "device_miss"
+    other.mkdir(exist_ok=True)
+    (other / f"{cell.cell_id}.json").write_text(json.dumps(stored))
+    res = runner.run_cell(cell, out=other, verbose=False)
+    assert not res.cached and calls == [cell.cell_id]
+    assert json.loads(res.path.read_text())["device"] == tpu
+
+
+def test_compile_cache_fixed_in_checkout_or_from_env(monkeypatch, tmp_path):
+    """Unset, the persistent compilation cache sits at the fixed
+    ``<repo>/.jax_cache``; ``JAX_COMPILATION_CACHE_DIR`` moves it there
+    and nowhere else."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+        fixed = hashing.repo_root() / ".jax_cache"
+        assert compile_cache.cache_dir() == fixed
+        assert compile_cache.enable() == fixed
+        assert jax.config.jax_compilation_cache_dir == str(fixed)
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+        assert compile_cache.enable() == tmp_path
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        cc.reset_cache()
 
 
 def test_guard_breach_exits_nonzero(probe_run, monkeypatch):

@@ -50,7 +50,7 @@ def test_matches_unrolled_ground_truth():
     x = jax.ShapeDtypeStruct((8, D), jnp.float32)
     w = jax.ShapeDtypeStruct((L, D, D), jnp.float32)
     cu, _ = _compile_text(unrolled, x, w)
-    xla_flops = H.xla_cost_analysis(cu)["flops"]
+    xla_flops = cu.cost_analysis()["flops"]
     _, text_s = _compile_text(scanned, x, w)
     ours = H.analyze(text_s)["flops_corrected"]
     assert abs(ours - xla_flops) / xla_flops < 0.2, (ours, xla_flops)

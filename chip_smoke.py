@@ -18,7 +18,7 @@ One chip:
    forms, 3 lanes), ``micro.dragonfly.adversarial.smoke`` (one-hot/GEMM
    forms, 512-packet flows) and ``serve.dragonfly.websearch.smoke``
    (segmented checkpoint/resume).  Per packet cell it prints build,
-   compile and warm-run seconds, and per cell the device's peak bytes.
+   compile and warm-run seconds.
 3. checks, each bit-identical on every ``SimResult`` field:
    (a) compressed == dense ``reference=True`` stepper, one DF-1056 lane;
    (b) ``use_kernels=True`` == the jnp path on DF-1056, solo and
@@ -87,11 +87,6 @@ def expect_same(label: str, got, want, fields=RESULT_FIELDS) -> None:
             raise SmokeFailure(f"{label}: lane {i} differs in {bad}")
     log(f"[check] {label}: {len(got)} lane(s) bit-identical "
         f"on {len(fields)} fields")
-
-
-def peak_bytes(dev) -> int:
-    stats = dev.memory_stats() or {}
-    return int(stats.get("peak_bytes_in_use", -1))
 
 
 def cell_spec(cell_id: str):
@@ -184,7 +179,7 @@ def phase_kernels(report) -> None:
             f"(smoke timing incl. compile {dt:.3f} s)")
 
 
-def phase_cells(report, dev) -> dict:
+def phase_cells(report) -> dict:
     """The three cells through ``repro.exp``; returns base specs."""
     from repro.exp import runner
     specs = {}
@@ -216,11 +211,10 @@ def phase_cells(report, dev) -> dict:
         wall = time.perf_counter() - t0
         (res,) = summary.results
         entry = report["cells"].setdefault(cell_id, {})
-        entry.update(cell_wall_s=wall, peak_bytes_in_use=peak_bytes(dev),
-                     guards_ok=res.ok, n_guards=len(res.guards))
+        entry.update(cell_wall_s=wall, guards_ok=res.ok,
+                     n_guards=len(res.guards))
         log(f"[cells] {cell_id}: repro.exp run {wall:.3f} s, "
-            f"{len(res.guards)} guards {'pass' if res.ok else 'FAIL'}, "
-            f"peak_bytes_in_use {entry['peak_bytes_in_use']}")
+            f"{len(res.guards)} guards {'pass' if res.ok else 'FAIL'}")
         if not res.ok or summary.breaches:
             raise SmokeFailure(f"{cell_id}: guard breach: {summary.breaches}")
         stored = json.loads(res.path.read_text())
@@ -372,7 +366,7 @@ def main(argv=None) -> int:
             phase_four_chips(report)
         else:
             phase_kernels(report)
-            specs = phase_cells(report, dev)
+            specs = phase_cells(report)
             phase_checks(report, specs)
             phase_cpu(report, specs)
     except Exception as e:  # any failure: no result line, non-zero exit

@@ -41,6 +41,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import ops as KOPS
 from repro.kernels.red_ecn import mark_scale
@@ -53,6 +54,23 @@ from repro.net.sim.types import (FB_ACK_ECN, FB_ACK_OK, FB_NACK, FB_NONE,
 
 INF_TICK = jnp.int32(1 << 30)
 _NEVER_SVC = -(1 << 30)   # last_svc sentinel: first service always legal
+
+# Device scopes (``jax.named_scope``) of the tick phases and the horizon:
+# the innermost one lands in every compiled instruction's ``op_name``, so a
+# profiler trace maps device time to phases whatever XLA names its fusions.
+# The policy scopes sit inside A_feedback and D_injection.  The benchmark
+# (``bench/program_trace.py``) reads these names.
+SCOPES = ("spritz.A0_failures", "spritz.A_feedback", "spritz.policy_feedback",
+          "spritz.B_service", "spritz.C_propagation", "spritz.D_injection",
+          "spritz.policy_send", "spritz.E_enqueue", "spritz.horizon")
+# Host spans (``jax.profiler.TraceAnnotation``) of ``run_batch``, on the
+# profiler's clock with the device planes: the whole call (``lanes``,
+# ``pad_lanes``, ``shards``), lane assembly, the runner lookup and call
+# (``runner_built`` = 1 where the call traced and compiled the runner),
+# and the per-lane result copies (``copied_bytes``, and ``steps``: the
+# longest lane's horizon steps, which every lane's loop ran for).
+SPANS = ("spritz.run_batch", "spritz.assemble_lanes", "spritz.dispatch",
+         "spritz.results")
 
 
 def _event_ivls(spec: SimSpec) -> np.ndarray:
@@ -385,8 +403,9 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
         t = t.astype(jnp.int32)
 
         # ------------- A0. failure timeline events (DESIGN.md §10) ----------
-        (port_up, port_ivl, last_svc, fail_idx, q_tail0, pstate0,
-         pevent0, trims0) = apply_failure_events(c, t)
+        with jax.named_scope("spritz.A0_failures"):
+            (port_up, port_ivl, last_svc, fail_idx, q_tail0, pstate0,
+             pevent0, trims0) = apply_failure_events(c, t)
 
         # load signal fed to the sender-policy layer: ticks-to-drain, so a
         # degraded port (interval > 1) advertises proportionally higher
@@ -402,238 +421,245 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
             static_path = spec_static
 
         # ---------------- A. feedback arrivals + timeouts -------------------
-        flow_sums = flow_sums_fn(c.pflow)
-        ack_m, nack_m, to_m, sums, fb_ev, fb_type = collect_feedback(
-            c, pstate0, pevent0, t, flow_sums)
-        n_ack, n_mark, n_nack, n_to, n_exp, n_exp_bad = sums
-        g2 = spec.dctcp_g
-        exp_alpha = jnp.where(
-            n_exp > 0,
-            (1 - g2) * c.exp_alpha + g2 * n_exp_bad / jnp.maximum(n_exp, 1),
-            c.exp_alpha)
+        with jax.named_scope("spritz.A_feedback"):
+            flow_sums = flow_sums_fn(c.pflow)
+            ack_m, nack_m, to_m, sums, fb_ev, fb_type = collect_feedback(
+                c, pstate0, pevent0, t, flow_sums)
+            n_ack, n_mark, n_nack, n_to, n_exp, n_exp_bad = sums
+            g2 = spec.dctcp_g
+            exp_alpha = jnp.where(
+                n_exp > 0,
+                (1 - g2) * c.exp_alpha + g2 * n_exp_bad / jnp.maximum(n_exp, 1),
+                c.exp_alpha)
 
-        cwnd, alpha, r_acks, r_marks, r_nacks, r_size = cc_round(
-            c, n_ack, n_mark, n_nack, n_to)
+            cwnd, alpha, r_acks, r_marks, r_nacks, r_size = cc_round(
+                c, n_ack, n_mark, n_nack, n_to)
 
-        # --- sender-policy feedback (one switch over registry branches) ---
-        fb_ctx = PB.FeedbackCtx(t=t, ev=fb_ev, fb_type=fb_type,
-                                ecn_rate=exp_alpha, n_mark=n_mark,
-                                n_nack=n_nack, n_to=n_to)
-        if batched:
-            policy = jax.lax.switch(jnp.clip(scheme, 0, n_pol - 1),
-                                    fb_brs, c.policy, fb_ctx)
-        else:
-            policy = fb_brs[scheme_code](c.policy, fb_ctx)
+            # --- sender-policy feedback (one switch over registry branches) ---
+            fb_ctx = PB.FeedbackCtx(t=t, ev=fb_ev, fb_type=fb_type,
+                                    ecn_rate=exp_alpha, n_mark=n_mark,
+                                    n_nack=n_nack, n_to=n_to)
+            with jax.named_scope("spritz.policy_feedback"):
+                if batched:
+                    policy = jax.lax.switch(jnp.clip(scheme, 0, n_pol - 1),
+                                            fb_brs, c.policy, fb_ctx)
+                else:
+                    policy = fb_brs[scheme_code](c.policy, fb_ctx)
 
-        acked = c.acked + n_ack
-        inflight = c.inflight - n_ack - n_nack - n_to
-        retx_pend = c.retx_pend + n_nack + n_to
-        done_now = (acked >= size_pkts) & (c.fct < 0)
-        fct = jnp.where(done_now, t - start_tick, c.fct)
+            acked = c.acked + n_ack
+            inflight = c.inflight - n_ack - n_nack - n_to
+            retx_pend = c.retx_pend + n_nack + n_to
+            done_now = (acked >= size_pkts) & (c.fct < 0)
+            fct = jnp.where(done_now, t - start_tick, c.fct)
 
-        # free finished packet slots
-        pstate = jnp.where(ack_m | nack_m | to_m, P_FREE, pstate0)
+            # free finished packet slots
+            pstate = jnp.where(ack_m | nack_m | to_m, P_FREE, pstate0)
 
         # ---------------- B. service (dequeue) ------------------------------
-        svc = (pstate == P_QUEUED) & (pevent0 == t)
-        cur_port = path_ports[c.pflow, c.ppath, c.phop]
-        plen = path_len[c.pflow, c.ppath]
-        at_delivery = c.phop == plen - 1
-        deliver = svc & at_delivery
-        forward = svc & ~at_delivery
+        with jax.named_scope("spritz.B_service"):
+            svc = (pstate == P_QUEUED) & (pevent0 == t)
+            cur_port = path_ports[c.pflow, c.ppath, c.phop]
+            plen = path_len[c.pflow, c.ppath]
+            at_delivery = c.phop == plen - 1
+            deliver = svc & at_delivery
+            forward = svc & ~at_delivery
 
-        # OOO accounting at delivery (<=1 delivery per flow per tick);
-        # sum == value since one packet delivers, via the same flow sums
-        dsums = flow_sums(jnp.stack([
-            jnp.where(deliver, c.ppsn, 0),
-            deliver.astype(jnp.int32),
-        ]))
-        dpsn, has_del = dsums[0], dsums[1] > 0
-        is_ooo = has_del & (dpsn != c.exp_psn)
-        ooo = c.ooo + is_ooo.astype(jnp.int32)
-        exp_psn = jnp.where(has_del, jnp.maximum(c.exp_psn, dpsn + 1),
-                            c.exp_psn)
+            # OOO accounting at delivery (<=1 delivery per flow per tick);
+            # sum == value since one packet delivers, via the same flow sums
+            dsums = flow_sums(jnp.stack([
+                jnp.where(deliver, c.ppsn, 0),
+                deliver.astype(jnp.int32),
+            ]))
+            dpsn, has_del = dsums[0], dsums[1] > 0
+            is_ooo = has_del & (dpsn != c.exp_psn)
+            ooo = c.ooo + is_ooo.astype(jnp.int32)
+            exp_psn = jnp.where(has_del, jnp.maximum(c.exp_psn, dpsn + 1),
+                                c.exp_psn)
 
-        # conformance counter: a service event must never cross a down port
-        # (the A0 kill rule + enqueue mask conspire to make this impossible)
-        cur_s = jnp.clip(cur_port, 0, NP_ - 1)
-        viol = c.viol + jnp.sum((svc & ~port_up[cur_s]).astype(jnp.int32))
-        rviol = c.rviol
-        if HAS_RATE:
-            # rate audit: services on one port must be >= its scheduled
-            # interval apart — throughput never exceeds the scheduled rate
-            rviol = rviol + jnp.sum(
-                (svc & (t - last_svc[cur_s] < port_ivl[cur_s])
-                 ).astype(jnp.int32))
-            last_svc = jnp.concatenate(
-                [last_svc, jnp.full((1,), _NEVER_SVC, jnp.int32)]).at[
-                jnp.where(svc, cur_port, NP_)].max(t)[:NP_]
+            # conformance counter: a service event must never cross a down port
+            # (the A0 kill rule + enqueue mask conspire to make this impossible)
+            cur_s = jnp.clip(cur_port, 0, NP_ - 1)
+            viol = c.viol + jnp.sum((svc & ~port_up[cur_s]).astype(jnp.int32))
+            rviol = c.rviol
+            if HAS_RATE:
+                # rate audit: services on one port must be >= its scheduled
+                # interval apart — throughput never exceeds the scheduled rate
+                rviol = rviol + jnp.sum(
+                    (svc & (t - last_svc[cur_s] < port_ivl[cur_s])
+                     ).astype(jnp.int32))
+                last_svc = jnp.concatenate(
+                    [last_svc, jnp.full((1,), _NEVER_SVC, jnp.int32)]).at[
+                    jnp.where(svc, cur_port, NP_)].max(t)[:NP_]
 
-        ret = ret_ticks[c.pflow, c.ppath]
-        pevent = jnp.where(deliver, t + ret, pevent0)
-        pstate = jnp.where(deliver, P_ACKWAIT, pstate)
-        pevent = jnp.where(forward, t + port_lat[cur_port], pevent)
-        pstate = jnp.where(forward, P_PROP, pstate)
+            ret = ret_ticks[c.pflow, c.ppath]
+            pevent = jnp.where(deliver, t + ret, pevent0)
+            pstate = jnp.where(deliver, P_ACKWAIT, pstate)
+            pevent = jnp.where(forward, t + port_lat[cur_port], pevent)
+            pstate = jnp.where(forward, P_PROP, pstate)
 
         # ---------------- C. propagation arrivals ---------------------------
-        arrive = (pstate == P_PROP) & (pevent == t)
-        phop = jnp.where(arrive, c.phop + 1, c.phop)
+        with jax.named_scope("spritz.C_propagation"):
+            arrive = (pstate == P_PROP) & (pevent == t)
+            phop = jnp.where(arrive, c.phop + 1, c.phop)
 
         # ---------------- D. injection --------------------------------------
-        work_left = (c.next_seq < size_pkts) | (retx_pend > 0)
-        eligible = (t >= start_tick) & (acked < size_pkts) & work_left & \
-                   (inflight < jnp.floor(cwnd).astype(jnp.int32)) & (c.fct < 0)
-        if has_dep:
-            fct_x = _padded(fct, 0)
-            dep_done = (dep < 0) | (fct_x[jnp.maximum(dep, -1)] >= 0)
-            # dep == -1 gathers fct_x[-1] == trash; masked by dep < 0 above
-            eligible = eligible & dep_done
-        # endpoint arbitration: one flow per source endpoint per tick
-        prio = ((t * jnp.int32(40503) + jnp.arange(F, dtype=jnp.int32) * 9973)
-                & 0xffff) + 1
-        prio = jnp.where(eligible, prio, 0)
-        key = prio * F + (F - 1 - jnp.arange(F, dtype=jnp.int32))  # unique
-        ep_best = jnp.zeros(n_eps, jnp.int32).at[src_ep].max(key)
-        win = eligible & (key == ep_best[src_ep])
+        with jax.named_scope("spritz.D_injection"):
+            work_left = (c.next_seq < size_pkts) | (retx_pend > 0)
+            eligible = (t >= start_tick) & (acked < size_pkts) & work_left & \
+                       (inflight < jnp.floor(cwnd).astype(jnp.int32)) & (c.fct < 0)
+            if has_dep:
+                fct_x = _padded(fct, 0)
+                dep_done = (dep < 0) | (fct_x[jnp.maximum(dep, -1)] >= 0)
+                # dep == -1 gathers fct_x[-1] == trash; masked by dep < 0 above
+                eligible = eligible & dep_done
+            # endpoint arbitration: one flow per source endpoint per tick
+            prio = ((t * jnp.int32(40503) + jnp.arange(F, dtype=jnp.int32) * 9973)
+                    & 0xffff) + 1
+            prio = jnp.where(eligible, prio, 0)
+            key = prio * F + (F - 1 - jnp.arange(F, dtype=jnp.int32))  # unique
+            ep_best = jnp.zeros(n_eps, jnp.int32).at[src_ep].max(key)
+            win = eligible & (key == ep_best[src_ep])
 
-        # free-slot allocation: k-th winner takes the k-th free slot, found
-        # by searchsorted over the free-count prefix (no N-sized scatter)
-        free_m = pstate == P_FREE
-        n_free = jnp.cumsum(free_m.astype(jnp.int32))
-        win_rank = jnp.cumsum(win.astype(jnp.int32)) - 1
-        have_slot = win & (win_rank < n_free[-1])
-        flow_slot = jnp.searchsorted(
-            n_free, jnp.maximum(win_rank, 0) + 1, side="left"
-        ).astype(jnp.int32)  # [F]; == N when out of slots (masked by tgt)
+            # free-slot allocation: k-th winner takes the k-th free slot, found
+            # by searchsorted over the free-count prefix (no N-sized scatter)
+            free_m = pstate == P_FREE
+            n_free = jnp.cumsum(free_m.astype(jnp.int32))
+            win_rank = jnp.cumsum(win.astype(jnp.int32)) - 1
+            have_slot = win & (win_rank < n_free[-1])
+            flow_slot = jnp.searchsorted(
+                n_free, jnp.maximum(win_rank, 0) + 1, side="left"
+            ).astype(jnp.int32)  # [F]; == N when out of slots (masked by tgt)
 
-        # --- path choice: one switch over the registry's choose_path
-        # branches.  Every policy's sampler consumes k_path through the
-        # identical uniform draw (policies.base.weighted_sample_rows), so
-        # the batched select and the specialized solo branch produce
-        # bit-identical choices per scheme (DESIGN.md §5/§11).
-        send_ctx = PB.SendCtx(rng=k_path, t=t, active=have_slot, occ=occ,
-                              weights=weights, static_path=static_path)
-        if batched:
-            path_sel, explored, policy = jax.lax.switch(
-                jnp.clip(scheme, 0, n_pol - 1), send_brs, policy, send_ctx)
-        else:
-            path_sel, explored, policy = send_brs[scheme_code](policy,
-                                                               send_ctx)
-        if has_bg:  # background jobs stay on static ECMP paths (paper §V-B)
-            path_sel = jnp.where(bg_mask, static_path, path_sel)
+            # --- path choice: one switch over the registry's choose_path
+            # branches.  Every policy's sampler consumes k_path through the
+            # identical uniform draw (policies.base.weighted_sample_rows), so
+            # the batched select and the specialized solo branch produce
+            # bit-identical choices per scheme (DESIGN.md §5/§11).
+            send_ctx = PB.SendCtx(rng=k_path, t=t, active=have_slot, occ=occ,
+                                  weights=weights, static_path=static_path)
+            with jax.named_scope("spritz.policy_send"):
+                if batched:
+                    path_sel, explored, policy = jax.lax.switch(
+                        jnp.clip(scheme, 0, n_pol - 1), send_brs, policy, send_ctx)
+                else:
+                    path_sel, explored, policy = send_brs[scheme_code](policy,
+                                                                       send_ctx)
+                if has_bg:  # background jobs stay on static ECMP paths (paper §V-B)
+                    path_sel = jnp.where(bg_mask, static_path, path_sel)
 
-        # write new packets (scatter via trash row N)
-        tgt = jnp.where(have_slot, flow_slot, N)
+            # write new packets (scatter via trash row N)
+            tgt = jnp.where(have_slot, flow_slot, N)
 
-        def scatter_new(arr, val):
-            big = jnp.concatenate([arr, jnp.zeros((1,), arr.dtype)])
-            big = big.at[tgt].set(val.astype(arr.dtype))
-            return big[:N]
+            def scatter_new(arr, val):
+                big = jnp.concatenate([arr, jnp.zeros((1,), arr.dtype)])
+                big = big.at[tgt].set(val.astype(arr.dtype))
+                return big[:N]
 
-        pflow = scatter_new(c.pflow, jnp.arange(F, dtype=jnp.int32))
-        ppath = scatter_new(c.ppath, path_sel)
-        phop = scatter_new(phop, jnp.zeros(F, jnp.int32))
-        psent = scatter_new(c.psent, jnp.full(F, t, jnp.int32))
-        ppsn = scatter_new(c.ppsn, c.inj_cnt)
-        pecn = scatter_new(c.pecn, jnp.zeros(F, bool))
-        pexp = scatter_new(c.pexp, explored)
-        pstate = scatter_new(pstate, jnp.full(F, P_PROP, jnp.int32))  # placeholder
-        pevent = scatter_new(pevent, jnp.full(F, t, jnp.int32))
-        # injected packets "arrive" at hop-0 port this tick:
-        injected_pkt = jnp.zeros(N + 1, bool).at[tgt].set(True)[:N]
+            pflow = scatter_new(c.pflow, jnp.arange(F, dtype=jnp.int32))
+            ppath = scatter_new(c.ppath, path_sel)
+            phop = scatter_new(phop, jnp.zeros(F, jnp.int32))
+            psent = scatter_new(c.psent, jnp.full(F, t, jnp.int32))
+            ppsn = scatter_new(c.ppsn, c.inj_cnt)
+            pecn = scatter_new(c.pecn, jnp.zeros(F, bool))
+            pexp = scatter_new(c.pexp, explored)
+            pstate = scatter_new(pstate, jnp.full(F, P_PROP, jnp.int32))  # placeholder
+            pevent = scatter_new(pevent, jnp.full(F, t, jnp.int32))
+            # injected packets "arrive" at hop-0 port this tick:
+            injected_pkt = jnp.zeros(N + 1, bool).at[tgt].set(True)[:N]
 
-        is_retx = have_slot & (retx_pend > 0)
-        retx_pend = retx_pend - is_retx.astype(jnp.int32)
-        next_seq = c.next_seq + (have_slot & ~is_retx).astype(jnp.int32)
-        inj_cnt = c.inj_cnt + have_slot.astype(jnp.int32)
-        inflight = inflight + have_slot.astype(jnp.int32)
-        retx_stat = c.retx + is_retx.astype(jnp.int32)
+            is_retx = have_slot & (retx_pend > 0)
+            retx_pend = retx_pend - is_retx.astype(jnp.int32)
+            next_seq = c.next_seq + (have_slot & ~is_retx).astype(jnp.int32)
+            inj_cnt = c.inj_cnt + have_slot.astype(jnp.int32)
+            inflight = inflight + have_slot.astype(jnp.int32)
+            retx_stat = c.retx + is_retx.astype(jnp.int32)
 
         # ---------------- E. enqueue (arrivals + injections) ----------------
-        enq0 = arrive | injected_pkt
-        eport_n = jnp.where(enq0, path_ports[pflow, ppath, phop], NP_)
-        failed = enq0 & (eport_n < NP_) & \
-            ~port_up[jnp.minimum(eport_n, NP_ - 1)]
-        enq = enq0 & ~failed
-        pstate = jnp.where(failed, P_LOST, pstate)
+        with jax.named_scope("spritz.E_enqueue"):
+            enq0 = arrive | injected_pkt
+            eport_n = jnp.where(enq0, path_ports[pflow, ppath, phop], NP_)
+            failed = enq0 & (eport_n < NP_) & \
+                ~port_up[jnp.minimum(eport_n, NP_ - 1)]
+            enq = enq0 & ~failed
+            pstate = jnp.where(failed, P_LOST, pstate)
 
-        # compact the <= M enqueues of this tick (M = n_ports + n_eps + 8:
-        # each port services <= 1 pkt/tick with a constant per-port latency,
-        # so forwarded arrivals are <= n_ports; endpoint arbitration admits
-        # <= 1 injection per endpoint) — all FIFO/RED/trim math runs in
-        # [M] instead of [N].
-        n_enq = jnp.cumsum(enq.astype(jnp.int32))
-        cidx = jnp.searchsorted(
-            n_enq, jnp.arange(M, dtype=jnp.int32) + 1, side="left"
-        ).astype(jnp.int32)  # [M]; == N past the last enqueue
-        valid = cidx < N
-        cidx_s = jnp.minimum(cidx, N)
-        cflow = _padded(pflow, F)[cidx_s]
-        cpath = _padded(ppath, 0)[cidx_s]
-        chop = _padded(phop, 0)[cidx_s]
-        cport = _padded(eport_n, NP_)[cidx_s]
+            # compact the <= M enqueues of this tick (M = n_ports + n_eps + 8:
+            # each port services <= 1 pkt/tick with a constant per-port latency,
+            # so forwarded arrivals are <= n_ports; endpoint arbitration admits
+            # <= 1 injection per endpoint) — all FIFO/RED/trim math runs in
+            # [M] instead of [N].
+            n_enq = jnp.cumsum(enq.astype(jnp.int32))
+            cidx = jnp.searchsorted(
+                n_enq, jnp.arange(M, dtype=jnp.int32) + 1, side="left"
+            ).astype(jnp.int32)  # [M]; == N past the last enqueue
+            valid = cidx < N
+            cidx_s = jnp.minimum(cidx, N)
+            cflow = _padded(pflow, F)[cidx_s]
+            cpath = _padded(ppath, 0)[cidx_s]
+            chop = _padded(phop, 0)[cidx_s]
+            cport = _padded(eport_n, NP_)[cidx_s]
 
-        # FIFO rank among same-tick arrivals per port (compacted)
-        rank = _enqueue_rank(cport)
+            # FIFO rank among same-tick arrivals per port (compacted)
+            rank = _enqueue_rank(cport)
 
-        tail_e = q_tail0[jnp.minimum(cport, NP_ - 1)]
-        if HAS_RATE:
-            # backlog in *packets* (buffer occupancy): ticks-to-drain
-            # divided by the port's service interval.  Trim/RED compare
-            # against packet thresholds (qsize/kmin/kmax), so a degraded
-            # port holds the same number of packets but drains slower.
-            # (kernel dispatch bypasses to jnp under HAS_RATE: red_ecn
-            # models the full-rate slot math only — DESIGN.md §14)
-            ivl_e = port_ivl[jnp.minimum(cport, NP_ - 1)]
-            occ_at = _ceildiv(jnp.maximum(tail_e - t, 0), ivl_e) + rank
-        elif use_kernels:
-            ivl_e = None
-            occ_at, trim, mark, slot = KOPS.red_ecn(
-                cport, rank, valid, jax.random.uniform(k_mark, (M,)),
-                q_tail0, t, qsize=spec.qsize, kmin=spec.kmin,
-                kmax=spec.kmax, n_ports=NP_)
-        else:
-            ivl_e = None
-            occ_at = jnp.maximum(tail_e - t, 0) + rank
-        if HAS_RATE or not use_kernels:
-            trim = valid & (occ_at >= spec.qsize)
-            # RED / ECN marking probability between kmin..kmax
-            pr = jnp.clip((occ_at.astype(jnp.float32) - spec.kmin)
-                          * mark_scale(spec.kmin, spec.kmax), 0.0, 1.0)
-            mark = (valid & ~trim) & (jax.random.uniform(k_mark, (M,)) < pr)
+            tail_e = q_tail0[jnp.minimum(cport, NP_ - 1)]
             if HAS_RATE:
-                # service slots stride by the interval: rank-k accept
-                # departs at max(tail, t) + (k+1)*ivl — rate 1/ivl by
-                # construction
-                slot = jnp.maximum(tail_e, t) + (rank + 1) * ivl_e
+                # backlog in *packets* (buffer occupancy): ticks-to-drain
+                # divided by the port's service interval.  Trim/RED compare
+                # against packet thresholds (qsize/kmin/kmax), so a degraded
+                # port holds the same number of packets but drains slower.
+                # (kernel dispatch bypasses to jnp under HAS_RATE: red_ecn
+                # models the full-rate slot math only — DESIGN.md §14)
+                ivl_e = port_ivl[jnp.minimum(cport, NP_ - 1)]
+                occ_at = _ceildiv(jnp.maximum(tail_e - t, 0), ivl_e) + rank
+            elif use_kernels:
+                ivl_e = None
+                occ_at, trim, mark, slot = KOPS.red_ecn(
+                    cport, rank, valid, jax.random.uniform(k_mark, (M,)),
+                    q_tail0, t, qsize=spec.qsize, kmin=spec.kmin,
+                    kmax=spec.kmax, n_ports=NP_)
             else:
-                slot = jnp.maximum(tail_e, t) + rank + 1
-        accept = valid & ~trim
-        pecn = pecn | jnp.zeros(N + 1, bool).at[
-            jnp.where(mark, cidx_s, N)].set(True)[:N]
-        # trimmed: header continues + NACK returns (priority, prop-only)
-        nack_at = t + rem_ticks[jnp.minimum(cflow, F - 1), cpath,
-                                jnp.minimum(chop, rem_ticks.shape[2] - 1)]
-        new_state = jnp.where(trim, P_NACKWAIT, P_QUEUED)
-        new_event = jnp.where(trim, nack_at, slot)
-        ctgt = jnp.where(valid, cidx_s, N)
-        pstate = _padded(pstate, 0).at[ctgt].set(
-            jnp.where(valid, new_state, 0))[:N]
-        pevent = _padded(pevent, 0).at[ctgt].set(
-            jnp.where(valid, new_event, 0))[:N]
+                ivl_e = None
+                occ_at = jnp.maximum(tail_e - t, 0) + rank
+            if HAS_RATE or not use_kernels:
+                trim = valid & (occ_at >= spec.qsize)
+                # RED / ECN marking probability between kmin..kmax
+                pr = jnp.clip((occ_at.astype(jnp.float32) - spec.kmin)
+                              * mark_scale(spec.kmin, spec.kmax), 0.0, 1.0)
+                mark = (valid & ~trim) & (jax.random.uniform(k_mark, (M,)) < pr)
+                if HAS_RATE:
+                    # service slots stride by the interval: rank-k accept
+                    # departs at max(tail, t) + (k+1)*ivl — rate 1/ivl by
+                    # construction
+                    slot = jnp.maximum(tail_e, t) + (rank + 1) * ivl_e
+                else:
+                    slot = jnp.maximum(tail_e, t) + rank + 1
+            accept = valid & ~trim
+            pecn = pecn | jnp.zeros(N + 1, bool).at[
+                jnp.where(mark, cidx_s, N)].set(True)[:N]
+            # trimmed: header continues + NACK returns (priority, prop-only)
+            nack_at = t + rem_ticks[jnp.minimum(cflow, F - 1), cpath,
+                                    jnp.minimum(chop, rem_ticks.shape[2] - 1)]
+            new_state = jnp.where(trim, P_NACKWAIT, P_QUEUED)
+            new_event = jnp.where(trim, nack_at, slot)
+            ctgt = jnp.where(valid, cidx_s, N)
+            pstate = _padded(pstate, 0).at[ctgt].set(
+                jnp.where(valid, new_state, 0))[:N]
+            pevent = _padded(pevent, 0).at[ctgt].set(
+                jnp.where(valid, new_event, 0))[:N]
 
-        trims = trims0 + jnp.zeros(F + 1, jnp.int32).at[
-            jnp.where(trim, cflow, F)].add(1)[:F]
-        timeouts = c.timeouts + n_to
-        delivered = c.delivered + n_ack
+            trims = trims0 + jnp.zeros(F + 1, jnp.int32).at[
+                jnp.where(trim, cflow, F)].add(1)[:F]
+            timeouts = c.timeouts + n_to
+            delivered = c.delivered + n_ack
 
-        # q_tail advances by ticks-of-service added: ivl per accepted
-        # packet (1 at full rate — the pre-rate scalar bump)
-        n_acc = jnp.zeros(NP_ + 1, jnp.int32).at[
-            jnp.where(accept, cport, NP_)].add(
-            1 if not HAS_RATE else ivl_e)[:NP_]
-        q_tail = jnp.where(n_acc > 0, jnp.maximum(q_tail0, t) + n_acc,
-                           q_tail0)
+            # q_tail advances by ticks-of-service added: ivl per accepted
+            # packet (1 at full rate — the pre-rate scalar bump)
+            n_acc = jnp.zeros(NP_ + 1, jnp.int32).at[
+                jnp.where(accept, cport, NP_)].add(
+                1 if not HAS_RATE else ivl_e)[:NP_]
+            q_tail = jnp.where(n_acc > 0, jnp.maximum(q_tail0, t) + n_acc,
+                               q_tail0)
 
         return Carry(
             rng=c.rng, q_tail=q_tail,
@@ -821,7 +847,11 @@ def _make_loop(spec: SimSpec, *, dense: bool, batched: bool):
 
         def body(s):
             c, t, steps = s
-            h = (t + 1) if dense else hor(c, t)
+            if dense:
+                h = t + 1
+            else:
+                with jax.named_scope("spritz.horizon"):
+                    h = hor(c, t)
             h = jnp.minimum(h, n_ticks)
             ex = h < n_ticks
             c2 = tick(c, jnp.minimum(h, n_ticks - 1), lane)
@@ -1092,21 +1122,37 @@ def run_batch(spec: SimSpec | Sequence[SimSpec],
     with the identical spec/schemes/seeds.  Segmenting is bit-identical
     to one unsegmented call, exactly as in :func:`run`.
     """
-    base, n_lanes, n_shard, args = _batch_inputs(
-        spec, schemes, seeds, stop_flows=stop_flows, shard=shard,
-        until_tick=until_tick, resume=resume)
-    runner = _runner(base, dense=reference, batched=True, shard=n_shard)
-    with warnings.catch_warnings():
-        # donation is a no-op on CPU; the advisory warning is noise there
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        carry, t, steps = runner(*args)
-    out, states = [], []
-    for i in range(n_lanes):  # pad lanes (lane-0 replicas) are dropped
-        lane_carry = jax.tree.map(lambda x: x[i], carry)
-        out.append(_result(lane_carry, t[i], steps[i]))
-        if return_carry:
-            states.append(_carry_state(lane_carry))
+    with TraceAnnotation("spritz.run_batch") as call:
+        with TraceAnnotation("spritz.assemble_lanes"):
+            base, n_lanes, n_shard, args = _batch_inputs(
+                spec, schemes, seeds, stop_flows=stop_flows, shard=shard,
+                until_tick=until_tick, resume=resume)
+        call.set_metadata(lanes=n_lanes,
+                          pad_lanes=len(args[-1].scheme) - n_lanes,
+                          shards=n_shard)
+        with TraceAnnotation("spritz.dispatch") as span:
+            runner = _runner(base, dense=reference, batched=True,
+                             shard=n_shard)
+            n_traced = runner._cache_size()
+            with warnings.catch_warnings():
+                # donation is a no-op on CPU; the advisory warning is noise
+                warnings.filterwarnings(
+                    "ignore", message="Some donated buffers were not usable")
+                carry, t, steps = runner(*args)
+            span.set_metadata(
+                runner_built=int(runner._cache_size() > n_traced))
+        with TraceAnnotation("spritz.results") as span:
+            out, states, copied = [], [], 0
+            for i in range(n_lanes):  # pad lanes (lane-0 replicas) dropped
+                lane_carry = jax.tree.map(lambda x: x[i], carry)
+                out.append(_result(lane_carry, t[i], steps[i]))
+                copied += sum(v.nbytes for v in out[-1]
+                              if isinstance(v, np.ndarray))
+                if return_carry:
+                    states.append(_carry_state(lane_carry))
+                    copied += live_carry_bytes(lane_carry)
+            span.set_metadata(copied_bytes=copied,
+                              steps=max(r.steps_executed for r in out))
     if return_carry:
         return out, states
     return out

@@ -11,7 +11,9 @@ preserved *analytically* with a service-slot counter per port:
 
 so there are no queue data structures at all — enqueue, RED/ECN marking,
 trimming, service, propagation, CC and the sender policy loop are dense
-array ops over the packet table.
+array ops over the packet table.  A packet's per-hop constants (port,
+next port, service-to-next-event delay) are resolved once, when it joins
+a queue, and carried in the table (the hop cache, DESIGN.md §14).
 
 Time advances by *event horizon* rather than tick-by-tick (DESIGN.md §4):
 every state change is anchored to an event tick (pending packet events,
@@ -113,6 +115,17 @@ class Carry(NamedTuple):
     pexp: jax.Array            # [N] bool (exploration/sampled packet)
     psent: jax.Array           # [N] i32
     ppsn: jax.Array            # [N] i32
+    # hop cache (DESIGN.md §14): the packet's current hop resolved once,
+    # when it enters a queue — written in D (hop 0, ``pport`` only) and in
+    # E (every enqueue).  For every P_QUEUED or P_PROP packet,
+    #   pport  == path_ports[pflow, ppath, phop]
+    #   pnport == path_ports[pflow, ppath, phop + 1], or n_ports when
+    #             phop == path_len[pflow, ppath] - 1 (the delivery hop)
+    #   pdelay == ret_ticks[pflow, ppath] at the delivery hop, else
+    #             port_lat[pport]
+    pport: jax.Array           # [N] i32 port of the current hop
+    pnport: jax.Array          # [N] i32 port of the next hop (n_ports: none)
+    pdelay: jax.Array          # [N] i32 ticks from service to next event
     # flow state
     next_seq: jax.Array        # [F] i32
     acked: jax.Array
@@ -250,7 +263,9 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
         # at the dying port are trimmed back (header NACK — the switch
         # drains its dead egress queue), packets already on the wire are
         # black-holed (P_LOST -> sender RTO); the analytic queue empties.
-        cur0 = path_ports[c.pflow, c.ppath, c.phop]
+        # ``pport`` is the port a P_QUEUED packet waits at and a P_PROP
+        # packet left from (phop advances only on arrival).
+        cur0 = c.pport
         hit = went_down[jnp.clip(cur0, 0, NP_ - 1)]
         killq = (c.pstate == P_QUEUED) & hit
         killp = (c.pstate == P_PROP) & hit
@@ -457,10 +472,11 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
 
         # ---------------- B. service (dequeue) ------------------------------
         with jax.named_scope("spritz.B_service"):
+            # the hop cache holds the port, the delivery flag and the
+            # delay of every queued packet: no path-table lookup over [N]
             svc = (pstate == P_QUEUED) & (pevent0 == t)
-            cur_port = path_ports[c.pflow, c.ppath, c.phop]
-            plen = path_len[c.pflow, c.ppath]
-            at_delivery = c.phop == plen - 1
+            cur_port = c.pport
+            at_delivery = c.pnport == NP_
             deliver = svc & at_delivery
             forward = svc & ~at_delivery
 
@@ -491,10 +507,8 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
                     [last_svc, jnp.full((1,), _NEVER_SVC, jnp.int32)]).at[
                     jnp.where(svc, cur_port, NP_)].max(t)[:NP_]
 
-            ret = ret_ticks[c.pflow, c.ppath]
-            pevent = jnp.where(deliver, t + ret, pevent0)
+            pevent = jnp.where(svc, t + c.pdelay, pevent0)
             pstate = jnp.where(deliver, P_ACKWAIT, pstate)
-            pevent = jnp.where(forward, t + port_lat[cur_port], pevent)
             pstate = jnp.where(forward, P_PROP, pstate)
 
         # ---------------- C. propagation arrivals ---------------------------
@@ -564,6 +578,8 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
             pexp = scatter_new(c.pexp, explored)
             pstate = scatter_new(pstate, jnp.full(F, P_PROP, jnp.int32))  # placeholder
             pevent = scatter_new(pevent, jnp.full(F, t, jnp.int32))
+            pport = scatter_new(c.pport, path_ports[
+                jnp.arange(F, dtype=jnp.int32), path_sel, 0])
             # injected packets "arrive" at hop-0 port this tick:
             injected_pkt = jnp.zeros(N + 1, bool).at[tgt].set(True)[:N]
 
@@ -577,7 +593,11 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
         # ---------------- E. enqueue (arrivals + injections) ----------------
         with jax.named_scope("spritz.E_enqueue"):
             enq0 = arrive | injected_pkt
-            eport_n = jnp.where(enq0, path_ports[pflow, ppath, phop], NP_)
+            # the port each enqueue joins: an arrival's cached next hop, an
+            # injection's hop-0 port (written in D)
+            eport_n = jnp.where(arrive, c.pnport,
+                                jnp.where(injected_pkt, pport, NP_))
+            pport = jnp.where(enq0, eport_n, pport)
             failed = enq0 & (eport_n < NP_) & \
                 ~port_up[jnp.minimum(eport_n, NP_ - 1)]
             enq = enq0 & ~failed
@@ -586,8 +606,9 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
             # compact the <= M enqueues of this tick (M = n_ports + n_eps + 8:
             # each port services <= 1 pkt/tick with a constant per-port latency,
             # so forwarded arrivals are <= n_ports; endpoint arbitration admits
-            # <= 1 injection per endpoint) — all FIFO/RED/trim math runs in
-            # [M] instead of [N].
+            # <= 1 injection per endpoint) — all FIFO/RED/trim math and the
+            # hop resolution of the enqueued packets run in [M] instead of
+            # [N].
             n_enq = jnp.cumsum(enq.astype(jnp.int32))
             cidx = jnp.searchsorted(
                 n_enq, jnp.arange(M, dtype=jnp.int32) + 1, side="left"
@@ -638,15 +659,28 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
             pecn = pecn | jnp.zeros(N + 1, bool).at[
                 jnp.where(mark, cidx_s, N)].set(True)[:N]
             # trimmed: header continues + NACK returns (priority, prop-only)
-            nack_at = t + rem_ticks[jnp.minimum(cflow, F - 1), cpath,
+            cflow_s = jnp.minimum(cflow, F - 1)
+            nack_at = t + rem_ticks[cflow_s, cpath,
                                     jnp.minimum(chop, rem_ticks.shape[2] - 1)]
             new_state = jnp.where(trim, P_NACKWAIT, P_QUEUED)
             new_event = jnp.where(trim, nack_at, slot)
+            # resolve the next hop of every enqueued packet (hop cache)
+            c_last = chop == path_len[cflow_s, cpath] - 1
+            new_nport = jnp.where(
+                c_last, NP_,
+                path_ports[cflow_s, cpath,
+                           jnp.minimum(chop + 1, path_ports.shape[2] - 1)])
+            new_delay = jnp.where(c_last, ret_ticks[cflow_s, cpath],
+                                  port_lat[jnp.minimum(cport, NP_ - 1)])
             ctgt = jnp.where(valid, cidx_s, N)
-            pstate = _padded(pstate, 0).at[ctgt].set(
-                jnp.where(valid, new_state, 0))[:N]
-            pevent = _padded(pevent, 0).at[ctgt].set(
-                jnp.where(valid, new_event, 0))[:N]
+
+            def scatter_enq(arr, val):
+                return _padded(arr, 0).at[ctgt].set(val)[:N]
+
+            pstate = scatter_enq(pstate, new_state)
+            pevent = scatter_enq(pevent, new_event)
+            pnport = scatter_enq(c.pnport, new_nport)
+            pdelay = scatter_enq(c.pdelay, new_delay)
 
             trims = trims0 + jnp.zeros(F + 1, jnp.int32).at[
                 jnp.where(trim, cflow, F)].add(1)[:F]
@@ -667,6 +701,7 @@ def build_tick(spec: SimSpec, *, batched: bool = False):
             fail_idx=fail_idx, viol=viol, rviol=rviol,
             pstate=pstate, pflow=pflow, ppath=ppath, phop=phop, pevent=pevent,
             pecn=pecn, pexp=pexp, psent=psent, ppsn=ppsn,
+            pport=pport, pnport=pnport, pdelay=pdelay,
             next_seq=next_seq, acked=acked, retx_pend=retx_pend,
             inflight=inflight, inj_cnt=inj_cnt, exp_psn=exp_psn,
             cwnd=cwnd, alpha=alpha, exp_alpha=exp_alpha,
@@ -798,6 +833,8 @@ def init_carry(spec: SimSpec, seed: int = 0,
         pevent=jnp.zeros(N, jnp.int32), pecn=jnp.zeros(N, bool),
         pexp=jnp.zeros(N, bool),
         psent=jnp.zeros(N, jnp.int32), ppsn=jnp.zeros(N, jnp.int32),
+        pport=jnp.zeros(N, jnp.int32), pnport=jnp.zeros(N, jnp.int32),
+        pdelay=jnp.zeros(N, jnp.int32),
         next_seq=jnp.zeros(F, jnp.int32), acked=jnp.zeros(F, jnp.int32),
         retx_pend=jnp.zeros(F, jnp.int32), inflight=jnp.zeros(F, jnp.int32),
         inj_cnt=jnp.zeros(F, jnp.int32), exp_psn=jnp.zeros(F, jnp.int32),

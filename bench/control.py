@@ -40,7 +40,8 @@ def readings(cell, seeds, control_seeds, *, log=print) -> dict:
         ref = harness.reference_lanes(cell, fab, fl, lane_seeds)
         if s in seeds:
             t = time.perf_counter()
-            got = E.run_batch(spec, list(cell.mix["schemes"]), lane_seeds)
+            got = E.run_batch(spec, list(cell.mix["schemes"]), lane_seeds,
+                              shard=cell.chips > 1)
             out["program"][s] = harness.mismatches(got, ref)
             log(json.dumps({"seed": s, "program_mismatches": out["program"][s],
                             "sweep_s": time.perf_counter() - t,

@@ -125,6 +125,7 @@ class Run:
     sweeps: list
     trace: dict | None = None   # tracing.reduce_events of the traced sweeps
     traced: int = 0             # the window's first ``traced`` sweeps
+    program: dict | None = None  # program_trace.reduce_program of the same trace
 
 
 # ---------------------------------------------------------------- set-up
@@ -199,7 +200,9 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool, t0: float,
     if require_tpu and platform != "tpu":
         raise BenchError(f"no TPU: JAX found {platform}; a benchmark never "
                          "runs on the CPU")
-    if len(devs) < cell.chips:
+    # the program shards a batch over every visible device, so a cell on
+    # several chips runs only where it sees exactly its own
+    if len(devs) < cell.chips or (cell.chips > 1 and len(devs) != cell.chips):
         raise BenchError(f"{cell.name} needs {cell.chips} chips, JAX sees "
                          f"{len(devs)}")
     log(f"device {platform} / {devs[0].device_kind} x {len(devs)}")
@@ -223,9 +226,10 @@ def _run(cell, seed, seconds, trace, t0, devs, compiles) -> dict:
     build_s = time.perf_counter() - tb
     schemes = list(cell.mix["schemes"])
     n_seeds = cell.mix["seeds_per_chip"] * cell.chips
+    shard = cell.chips > 1
     # warm the window's one program (and the per-lane host ops) on a few
     # ticks: ``until_tick`` is a traced bound, so this is the same program
-    E.run_batch(spec, schemes, lane_seeds(seed, 0, n_seeds),
+    E.run_batch(spec, schemes, lane_seeds(seed, 0, n_seeds), shard=shard,
                 until_tick=int(cell.mix["warmup_ticks"]))
     n_setup_compiles = len(compiles.events)
     compile_s = compiles.seconds()
@@ -242,7 +246,7 @@ def _run(cell, seed, seconds, trace, t0, devs, compiles) -> dict:
         seeds = lane_seeds(seed, len(sweeps), n_seeds)
         ts = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.sweep"):
-            res = E.run_batch(spec, schemes, seeds)
+            res = E.run_batch(spec, schemes, seeds, shard=shard)
         sweeps.append(Sweep(seeds, res, time.perf_counter() - ts))
         if trace and len(sweeps) == TRACED_SWEEPS:
             jax.profiler.stop_trace()
@@ -255,8 +259,9 @@ def _run(cell, seed, seconds, trace, t0, devs, compiles) -> dict:
                compile_s=compile_s, sweeps=sweeps,
                traced=min(len(sweeps), TRACED_SWEEPS) if trace else 0)
     if trace:
-        from tracing import reduce_trace
-        run_.trace = reduce_trace(TRACE_DIR, n_devices=cell.chips)
+        import program_trace
+        run_.trace, run_.program = program_trace.reduce_both(
+            TRACE_DIR, n_devices=cell.chips)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
 
     pick = int(np.random.default_rng([seed % (1 << 64), 7]).integers(len(sweeps)))

@@ -40,9 +40,11 @@ import sys
 from collections import Counter, defaultdict
 from pathlib import Path
 
-from tracing import DEVICE_PLANE, OPS_LINE, gaps, leaves, union
+import tracing
+from tracing import DEVICE_PLANE, OPS_LINE, gaps, innermost, leaves, union
 
 PREFIX = "spritz."
+BENCH = "bench."
 SWEEP = "bench.sweep"
 CALL = "spritz.run_batch"
 POLICY = ("spritz.policy_feedback", "spritz.policy_send")
@@ -68,11 +70,6 @@ def outer_loops(ops) -> list[tuple[float, float]]:
         if is_while(n):
             out.append((s, e))
     return out
-
-
-def _innermost(spans, t: float):
-    inside = [(e - s, n) for s, e, n, _ in spans if s <= t <= e]
-    return min(inside)[1] if inside else None
 
 
 def _partition(pieces, labels) -> dict:
@@ -102,16 +99,16 @@ def _labels(lo, hi, loops, waits, spans, sweeps) -> list:
     def within(ivs, t):
         return any(s <= t <= e for s, e in ivs)
 
+    mids = [(a + b) / 2 for a, b in zip(cuts, cuts[1:])]
+    inner = innermost([sp[:3] for sp in spans], mids)
     out = []
-    for a, b in zip(cuts, cuts[1:]):
-        m = (a + b) / 2
+    for a, b, m, span in zip(cuts, cuts[1:], mids, inner):
         if within(loops, m):
             lab = "device_loop"
         elif within(waits, m):
             lab = "chip_wait"
         else:
-            lab = _innermost(spans, m) or (
-                "bench" if within(sweeps, m) else "outside_sweeps")
+            lab = span or ("bench" if within(sweeps, m) else "outside_sweeps")
         out.append((a, b, lab))
     return out
 
@@ -298,10 +295,14 @@ def _interval(t0: int, ev) -> tuple[float, float]:
 
 
 def read_events(path: Path):
-    """(host spans with their arguments, device ops with their scopes)
-    from one ``.xplane.pb``: spans are (start_ns, end_ns, name, args) of
-    the ``bench.*`` and ``spritz.*`` host events, ops {chip: [(start_ns,
-    end_ns, HLO text, scope or None)]} of each chip's ``XLA Ops`` line."""
+    """(host spans with their arguments, device ops with their scopes,
+    host runtime events) from one ``.xplane.pb``: spans are (start_ns,
+    end_ns, name, args) of the ``bench.*`` and ``spritz.*`` host events,
+    ops {chip: [(start_ns, end_ns, HLO text, scope or None)]} of each
+    chip's ``XLA Ops`` line, and runtime events (start_ns, end_ns, name)
+    the other events of the host lines that hold a ``bench.*`` span,
+    ``$``-named Python frames left out (``tracing.reduce_events``' three
+    inputs are these, cut to three fields)."""
     space = _messages()["XSpace"].FromString(Path(path).read_bytes())
     scopes: dict = {}            # program id -> {instruction: scope}
     for plane in space.planes:
@@ -310,7 +311,7 @@ def read_events(path: Path):
                 m = _PROGRAM.search(md.name)
                 if m and md.stats:
                     scopes[int(m.group(1))] = hlo_scopes(md.stats[0].bytes_value)
-    spans, ops = [], {}
+    spans, ops, runtime = [], {}, []
     for plane in space.planes:
         names = {k: v.name for k, v in plane.stat_metadata.items()}
         meta = plane.event_metadata
@@ -328,20 +329,38 @@ def read_events(path: Path):
                     (*_interval(t0, ev), *where[ev.metadata_id])
                     for ev in line.events]
             elif plane.name.startswith("/host:"):
-                for ev in line.events:
-                    name = meta[ev.metadata_id].name
-                    if name.startswith((PREFIX, SWEEP)):
-                        spans.append((*_interval(t0, ev), name,
-                                      _stats(ev.stats, names)))
-    return spans, ops
+                evs = [(*_interval(t0, ev), meta[ev.metadata_id].name, ev)
+                       for ev in line.events]
+                spans += [(s, e, n, _stats(ev.stats, names))
+                          for s, e, n, ev in evs if n.startswith((PREFIX, BENCH))]
+                if any(n.startswith(BENCH) for _, _, n, _ in evs):
+                    runtime += [(s, e, n) for s, e, n, _ in evs
+                                if not n.startswith((BENCH, "$"))]
+    return spans, ops, runtime
+
+
+def read_trace(trace_dir: Path):
+    """``read_events`` of the newest trace under ``trace_dir``."""
+    files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
+    if not files:
+        raise ValueError(f"no profiler trace under {trace_dir}")
+    return read_events(files[-1])
 
 
 def reduce_trace(trace_dir: Path, n_devices: int) -> dict:
     """The tables of the newest trace under ``trace_dir``."""
-    files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
-    if not files:
-        raise ValueError(f"no profiler trace under {trace_dir}")
-    return reduce_program(*read_events(files[-1]), n_devices=n_devices)
+    spans, ops, _ = read_trace(trace_dir)
+    return reduce_program(spans, ops, n_devices=n_devices)
+
+
+def reduce_both(trace_dir: Path, n_devices: int) -> tuple[dict, dict]:
+    """``tracing.reduce_events`` and ``reduce_program`` of the newest trace
+    under ``trace_dir``, from one reading of it."""
+    spans, ops, runtime = read_trace(trace_dir)
+    device = tracing.reduce_events(
+        [sp[:3] for sp in spans if sp[2].startswith(BENCH)],
+        {c: [op[:3] for op in v] for c, v in ops.items()}, n_devices, runtime)
+    return device, reduce_program(spans, ops, n_devices=n_devices)
 
 
 def steps(out: dict) -> int | None:

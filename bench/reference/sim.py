@@ -378,13 +378,19 @@ def simulate(tables: Tables, flows: dict, sz: Sizes, n_ports: int, lanes,
     ``lanes``, a list of (scheme, seed); ``flows`` holds the ``src``,
     ``dst``, ``size`` and ``start`` arrays."""
     out: list = [None] * len(lanes)
+    devices, runs = jax.devices(), []
     for scheme in SCHEMES:
         idx = [i for i, (sc, _) in enumerate(lanes) if sc == scheme]
         if not idx:
             continue
-        run = make_lane_runner(tables, flows, sz, n_ports, scheme, dtype)
-        res = jax.device_get(run(jnp.asarray([lanes[i][1] for i in idx],
-                                             jnp.int32)))
+        # each scheme on a device of its own where there are several: every
+        # run is dispatched before any result is read, so they overlap
+        with jax.default_device(devices[len(runs) % len(devices)]):
+            run = make_lane_runner(tables, flows, sz, n_ports, scheme, dtype)
+            runs.append((idx, run(jnp.asarray([lanes[i][1] for i in idx],
+                                              jnp.int32))))
+    for idx, res in runs:
+        res = jax.device_get(res)
         for j, i in enumerate(idx):
             lane = {k: np.asarray(v[j]) for k, v in res.items()}
             lane["down_violations"] = np.int32(0)

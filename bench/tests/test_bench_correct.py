@@ -4,6 +4,9 @@ program agrees with the plain reference value for value, the control
 timed path is broken underneath reports ``correct`` false."""
 from __future__ import annotations
 
+import json
+import os
+import subprocess
 import sys
 import time
 from pathlib import Path
@@ -116,3 +119,36 @@ def test_sound_run_is_correct(cell):
     assert out["attempted"] > 0 and out["attempted"] % 6 == 0
     assert out["failed"] == 0
     assert set(out["metrics"]) == {"setup_s", "sweep_s"}
+
+
+FOUR_DEVICES = """
+import json, sys, time
+import pytest
+sys.path.insert(0, sys.argv[1])
+import test_bench_correct as T
+cell = T.harness.resolve("df72.permutation.x4", T.DATA / "manifest.json",
+                         T.DATA / "traffic")
+for fault in (None, "no_exchange"):
+    with pytest.MonkeyPatch.context() as mp:
+        if fault:
+            T.FAULTS[fault](mp)
+        out = T.harness.run(cell, seed=2**31 + 3, seconds=0.1, trace=False,
+                            t0=time.perf_counter(), require_tpu=False)
+    print(json.dumps({"fault": fault, "correct": out["correct"],
+                      "count": out["device"]["count"], **out["checks"]}))
+"""
+
+
+def test_four_chip_cell_on_four_devices():
+    """The lane-sharded path through the whole run, on four virtual CPU
+    devices: a sound run is correct, and one whose lanes past the first
+    device's share are lost is not."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", FOUR_DEVICES,
+                        str(BENCH / "tests")], env=env, cwd=ROOT,
+                       capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    sound, lost = [json.loads(line) for line in p.stdout.splitlines()[-2:]]
+    assert sound["correct"] is True and sound["count"] == 4, sound
+    assert lost["correct"] is False and lost["mismatched_values"]["value"] > 0

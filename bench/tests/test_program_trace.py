@@ -188,14 +188,22 @@ def test_read_events_maps_ops_to_scopes_through_the_hlo(tmp_path, capsys):
     path.parent.mkdir()
     path.write_bytes(space.SerializeToString())
 
-    spans, ops = program_trace.read_events(path)
+    spans, ops, runtime = program_trace.read_events(path)
     assert spans == [(1000, 2000, "bench.sweep", {}),
                      (1000, 2000, "spritz.run_batch", {"lanes": 3})]
+    assert runtime == [(1000, 2000, "spritz.run_batch"), (1000, 1005, "other")]
     assert ops == {0: [(1100, 1900, "%while.1 = s32[] op()", None),
                        (1200, 1500, "%fusion.2 = s32[] op()",
                         "spritz.E_enqueue"),
                        (1600, 1850, "%fusion.3 = s32[] op()",
                         "spritz.B_service")]}
+    device, program = program_trace.reduce_both(tmp_path, n_devices=1)
+    assert program == program_trace.reduce_trace(tmp_path, n_devices=1)
+    assert device["window_s"] == pytest.approx(1000e-9)
+    assert device["busy_s"] == pytest.approx(550e-9)  # the two fusions
+    idle = device["breakdown"]["idle_gaps"]
+    assert [g[0] for g in idle] == ["bench.sweep:spritz.run_batch@chip0"] * 3
+    assert [g[1] for g in idle] == pytest.approx([200e-9, 150e-9, 100e-9])
     assert program_trace.main([str(tmp_path)]) == 0
     out = capsys.readouterr().out
     assert "spritz.E_enqueue" in out and "spritz.B_service" in out

@@ -9,12 +9,13 @@ idle.  Each idle gap is put down to the innermost benchmark span
 (``bench.*``) covering its midpoint, refined by the innermost runtime
 event on that span's host thread (dispatch, transfer, allocation), or
 ``host`` where the runtime recorded nothing: Python work on the host.
+``program_trace.read_events`` reads the events from the trace's file.
 """
 from __future__ import annotations
 
+import heapq
 import re
 from collections import defaultdict
-from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
@@ -68,9 +69,26 @@ def gaps(busy, lo: float, hi: float) -> list[tuple[float, float]]:
     return [(s, e) for s, e in out if e > s]
 
 
-def innermost(events, t: float):
-    inside = [(e - s, n) for s, e, n in events if s <= t <= e]
-    return min(inside)[1] if inside else None
+def innermost(events, times) -> list:
+    """For each of ``times``, the name of the shortest event (start, end,
+    name) whose closed interval holds it, the smaller name on a tie, or
+    None: one pass over the times in order, with the events that have
+    started on a heap keyed by (duration, name)."""
+    evs = sorted(events)
+    out: list = [None] * len(times)
+    heap: list = []
+    i = 0
+    for k in sorted(range(len(times)), key=times.__getitem__):
+        t = times[k]
+        while i < len(evs) and evs[i][0] <= t:
+            s, e, n = evs[i]
+            heapq.heappush(heap, (e - s, n, e))
+            i += 1
+        while heap and heap[0][2] < t:
+            heapq.heappop(heap)
+        if heap:
+            out[k] = heap[0][1]
+    return out
 
 
 def reduce_events(host_spans, device_ops, n_devices: int,
@@ -85,7 +103,7 @@ def reduce_events(host_spans, device_ops, n_devices: int,
     chips = sorted(device_ops)[:n_devices]
     if len(chips) < n_devices:
         raise ValueError(f"the trace holds {len(chips)} chips, not {n_devices}")
-    busy, op_time, gap_list = {}, defaultdict(float), []
+    busy, op_time, idle = {}, defaultdict(float), []
     for c in chips:
         ops = [(max(s, lo), min(e, hi), n) for s, e, n in leaves(device_ops[c])
                if e > lo and s < hi]
@@ -93,11 +111,13 @@ def reduce_events(host_spans, device_ops, n_devices: int,
         busy[c] = sum(e - s for s, e in merged) * 1e-9
         for s, e, n in ops:
             op_time[short_name(n)] += (e - s) * 1e-9
-        for s, e in gaps(merged, lo, hi):
-            mid = (s + e) / 2
-            span = innermost(host_spans, mid) or "outside_bench_spans"
-            what = innermost(host_events, mid) or "host"
-            gap_list.append(((e - s) * 1e-9, f"{span}:{what}@chip{c}"))
+        idle += [(s, e, c) for s, e in gaps(merged, lo, hi)]
+    mids = [(s + e) / 2 for s, e, _ in idle]
+    gap_list = [((e - s) * 1e-9, f"{span or 'outside_bench_spans'}:"
+                 f"{what or 'host'}@chip{c}")
+                for (s, e, c), span, what in zip(
+                    idle, innermost(host_spans, mids),
+                    innermost(host_events, mids))]
     window_s = (hi - lo) * 1e-9
     gap_list.sort(reverse=True)
     return {
@@ -111,34 +131,3 @@ def reduce_events(host_spans, device_ops, n_devices: int,
             "idle_gaps": [[label, t] for t, label in gap_list[:TOP]],
         },
     }
-
-
-def read_events(path: Path):
-    """(host spans, device ops, host runtime events) from one
-    ``.xplane.pb``."""
-    import jax
-    data = jax.profiler.ProfileData.from_file(str(path))
-    spans, ops, runtime = [], {}, []
-    for plane in data.planes:
-        m = DEVICE_PLANE.match(plane.name)
-        for line in plane.lines:
-            if m and line.name == OPS_LINE:
-                ops[int(m.group(1))] = [
-                    (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
-                    for ev in line.events]
-            elif plane.name.startswith("/host:"):
-                evs = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
-                       for ev in line.events]
-                mine = [e for e in evs if e[2].startswith("bench.")]
-                if mine:
-                    spans += mine
-                    runtime += [e for e in evs if not e[2].startswith(("bench.", "$"))]
-    return spans, ops, runtime
-
-
-def reduce_trace(trace_dir: Path, n_devices: int) -> dict:
-    files = sorted(Path(trace_dir).rglob("*.xplane.pb"))
-    if not files:
-        raise ValueError(f"no profiler trace under {trace_dir}")
-    spans, ops, runtime = read_events(files[-1])
-    return reduce_events(spans, ops, n_devices, runtime)

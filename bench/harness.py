@@ -3,8 +3,9 @@ sweeps through ``engine.run_batch``, the optional profiler trace, the
 comparison with the plain reference, and the result line.
 
 Everything that belongs to a cell is found by name from
-``BENCHMARK.json``: the configuration file, ``traffic/<mix>.json`` and
-``metrics/<metric>.py`` for each per-layer metric.
+``BENCHMARK.json``: the configuration file, ``traffic/<mix>.json`` (whose
+``pattern`` names ``traffic/<pattern>.py``) and ``metrics/<metric>.py``
+for each per-layer metric.
 """
 from __future__ import annotations
 
@@ -30,6 +31,7 @@ COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration",
                   "/jax/compilation_cache/cache_retrieval_time_sec")
 LIMIT_MISMATCHES = 0   # the simulation is exact: every value must agree
+PKT_SLOTS = 1 << 16    # packet-table cap where the mix states no ``pkt_slots``
 TRACED_SWEEPS = 2      # a --trace 1 run traces the window's first sweeps
 
 
@@ -138,7 +140,14 @@ def reference_fabric(cell: Cell):
 def make_flows(cell: Cell, fab) -> dict:
     from traffic import generator
     group = np.asarray([fab.endpoint_group(e) for e in range(fab.n_endpoints)])
-    return generator.flows(cell.mix, group)
+    return generator.flows(cell.mix, group, cell.config)
+
+
+def pkt_slots(cell: Cell) -> int:
+    """The packet-table cap both the program and the reference size by:
+    while it binds, senders wait for a free slot, so it is part of the
+    result."""
+    return int(cell.mix.get("pkt_slots", PKT_SLOTS))
 
 
 def program_spec(cell: Cell, fl: dict):
@@ -153,7 +162,8 @@ def program_spec(cell: Cell, fl: dict):
                         n_ticks=int(cell.mix["n_ticks"]),
                         seed=int(cell.config["path_seed"]),
                         max_paths=int(cell.config["max_paths"]),
-                        w_scale=float(cell.config["w_scale"]))
+                        w_scale=float(cell.config["w_scale"]),
+                        n_pkt_cap=pkt_slots(cell))
 
 
 # ------------------------------------------------------------- correctness
@@ -169,7 +179,8 @@ def reference_lanes(cell: Cell, fab, fl: dict, seeds: list, dtype=None):
                             w_scale=float(cfg["w_scale"]),
                             path_seed=int(cfg["path_seed"]))
     sz = RS.sizes(tables, fl, bdp=int(cfg["bdp_packets"]),
-                  n_ticks=int(cell.mix["n_ticks"]), n_ports=fab.n_ports)
+                  n_ticks=int(cell.mix["n_ticks"]), n_ports=fab.n_ports,
+                  pkt_slots=pkt_slots(cell))
     lanes = [(s, seeds[k]) for s, k in cell.lanes]
     return [types.SimpleNamespace(**r) for r in RS.simulate(
         tables, fl, sz, fab.n_ports, lanes, dtype=dtype or jnp.float32)]

@@ -85,10 +85,12 @@ class Sizes:
 
 
 def sizes(tables: Tables, flows: dict, *, bdp: int, n_ticks: int,
-          n_ports: int) -> Sizes:
+          n_ports: int, pkt_slots: int) -> Sizes:
+    """``pkt_slots`` caps the packet table: the flows' first windows and
+    64 slots more, or the cap where that is fewer."""
     cwnd_max = 1.5 * bdp
-    n_pkt = int(min(1 << 16, sum(min(int(s), int(cwnd_max) + 4)
-                                 for s in flows["size"]) + 64))
+    n_pkt = int(min(pkt_slots, sum(min(int(s), int(cwnd_max) + 4)
+                                   for s in flows["size"]) + 64))
     rto = int(2.5 * (bdp * int(tables.length.max()) + int(tables.ret.max())))
     n_eps = int(np.max(flows["src"])) + 1
     return Sizes(bdp=bdp, n_ticks=n_ticks, n_pkt=n_pkt, rto=rto,

@@ -1,9 +1,11 @@
 """The comparison that decides ``correct``, at a CPU test size: the
-program agrees with the plain reference value for value, the control
-(the reference with its float arithmetic in bfloat16) does not, and a run whose
-timed path is broken underneath reports ``correct`` false."""
+program agrees with the plain reference value for value, under the
+permutation and under open-loop web-search traffic, the control (the
+reference with its float arithmetic in bfloat16) does not, and a run
+whose timed path is broken underneath reports ``correct`` false."""
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -119,6 +121,54 @@ def test_sound_run_is_correct(cell):
     assert out["attempted"] > 0 and out["attempted"] % 6 == 0
     assert out["failed"] == 0
     assert set(out["metrics"]) == {"setup_s", "sweep_s"}
+
+
+@pytest.fixture(scope="module")
+def websearch():
+    """``df72.websearch``: open-loop flows over a packet table that binds."""
+    cell = harness.resolve("df72.websearch", DATA / "manifest.json",
+                           DATA / "traffic")
+    fab = harness.reference_fabric(cell)
+    fl = harness.make_flows(cell, fab)
+    return cell, fab, fl, harness.program_spec(cell, fl)
+
+
+def test_websearch_program_matches_reference(websearch):
+    """Equal value for value, with staggered starts, horizon jumps on every
+    lane and senders waiting for a free packet slot."""
+    cell, fab, fl, spec = websearch
+    seeds = harness.lane_seeds(2**31 + 78, 0, 2)
+    got = E.run_batch(spec, list(cell.mix["schemes"]), seeds)
+    ref = harness.reference_lanes(cell, fab, fl, seeds)
+    assert harness.mismatches(got, ref) == 0
+    assert all(np.all(r.done) for r in got)
+    assert all(r.steps_executed < r.ticks_simulated for r in got)
+    assert len(set(fl["start"].tolist())) > 1
+    window = int(1.5 * cell.config["bdp_packets"]) + 4
+    assert spec.n_pkt == cell.mix["pkt_slots"] < \
+        np.minimum(fl["size"], window).sum() + 64
+    # where the mix states no pool, the reference's results differ: the
+    # stated one binds
+    unbound = dataclasses.replace(
+        cell, mix={k: v for k, v in cell.mix.items() if k != "pkt_slots"})
+    assert harness.mismatches(
+        harness.reference_lanes(unbound, fab, fl, seeds), ref) > 0
+
+
+def test_websearch_control_fails_the_comparison(websearch):
+    cell, fab, fl, _ = websearch
+    seeds = harness.lane_seeds(6, 0, 2)
+    ref = harness.reference_lanes(cell, fab, fl, seeds)
+    ctl = harness.reference_lanes(cell, fab, fl, seeds, dtype=jnp.bfloat16)
+    assert harness.mismatches(ctl, ref) > harness.LIMIT_MISMATCHES
+
+
+def test_websearch_sound_run_is_correct(websearch):
+    cell = websearch[0]
+    out = harness.run(cell, seed=2**31 + 4, seconds=0.1, trace=False,
+                      t0=time.perf_counter(), require_tpu=False)
+    assert out["correct"] is True, out["checks"]
+    assert out["failed"] == 0 and out["attempted"] % 6 == 0
 
 
 FOUR_DEVICES = """

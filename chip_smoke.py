@@ -121,7 +121,8 @@ def timed_batch(spec, schemes, seeds, stop_flows=None):
     t2 = time.perf_counter()
     carry, t, steps = jax.block_until_ready(compiled(*args))
     t3 = time.perf_counter()
-    res = [E._result(jax.tree.map(lambda x: x[i], carry), t[i], steps[i])
+    leaves, t, steps = E._fetch_results(carry, t, steps)
+    res = [E._result(E._ResultLeaves(*(x[i] for x in leaves)), t[i], steps[i])
            for i in range(n_lanes)]
     return res, compiled, {"inputs_s": t1 - t0, "compile_s": t2 - t1,
                            "run_s": t3 - t2}

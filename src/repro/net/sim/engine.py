@@ -69,8 +69,10 @@ SCOPES = ("spritz.A0_failures", "spritz.A_feedback", "spritz.policy_feedback",
 # profiler's clock with the device planes: the whole call (``lanes``,
 # ``pad_lanes``, ``shards``), lane assembly, the runner lookup and call
 # (``runner_built`` = 1 where the call traced and compiled the runner),
-# and the per-lane result copies (``copied_bytes``, and ``steps``: the
-# longest lane's horizon steps, which every lane's loop ran for).
+# and the result copies (``copied_bytes`` of the per-lane arrays returned,
+# ``transfers``: device arrays fetched, whatever the lane count, and
+# ``steps``: the longest lane's horizon steps, which every lane's loop
+# ran for).
 SPANS = ("spritz.run_batch", "spritz.assemble_lanes", "spritz.dispatch",
          "spritz.results")
 
@@ -968,19 +970,43 @@ def _watch_mask(spec: SimSpec, stop_flows) -> np.ndarray:
     return m
 
 
-def _result(carry: Carry, t, steps) -> SimResult:
+class _ResultLeaves(NamedTuple):
+    """The carry leaves a :class:`SimResult` is read from."""
+
+    fct: np.ndarray
+    delivered: np.ndarray
+    trims: np.ndarray
+    timeouts: np.ndarray
+    ooo: np.ndarray
+    retx: np.ndarray
+    viol: np.ndarray
+    rviol: np.ndarray
+
+
+def _fetch_results(carry: Carry, t, steps):
+    """``(leaves, t, steps)`` on the host, in one ``jax.device_get`` of
+    the ten arrays a result reads (lane-stacked in a batched carry).
+    ``device_get`` starts every transfer before it waits on any, and
+    copies a lane-sharded array shard by shard from its own chip: no
+    op runs on the device, so nothing compiles here."""
+    leaves = _ResultLeaves(*(getattr(carry, f) for f in _ResultLeaves._fields))
+    return jax.device_get((leaves, t, steps))
+
+
+def _result(leaves: _ResultLeaves, t, steps) -> SimResult:
+    """One lane's result from its host arrays (see ``_fetch_results``)."""
     return SimResult(
-        fct_ticks=np.asarray(carry.fct),
-        delivered=np.asarray(carry.delivered),
-        trims=np.asarray(carry.trims),
-        timeouts=np.asarray(carry.timeouts),
-        ooo=np.asarray(carry.ooo),
-        retx=np.asarray(carry.retx),
-        done=np.asarray(carry.fct >= 0),
+        fct_ticks=leaves.fct,
+        delivered=leaves.delivered,
+        trims=leaves.trims,
+        timeouts=leaves.timeouts,
+        ooo=leaves.ooo,
+        retx=leaves.retx,
+        done=leaves.fct >= 0,
         ticks_simulated=int(t),
         steps_executed=int(steps),
-        down_violations=int(carry.viol),
-        rate_violations=int(carry.rviol),
+        down_violations=int(leaves.viol),
+        rate_violations=int(leaves.rviol),
     )
 
 
@@ -994,10 +1020,10 @@ def live_carry_bytes(carry: Carry) -> int:
 
 
 def _carry_state(carry: Carry) -> dict:
-    """Final carry as nested NumPy dicts — the observability hook the
-    conservation/conformance property suites audit.  The stacked policy
-    dict lands under ``"policy"``; ``"spritz"`` stays a top-level alias
-    for pre-refactor callers."""
+    """Final carry, fetched to the host, as nested NumPy dicts — the
+    observability hook the conservation/conformance property suites
+    audit.  The stacked policy dict lands under ``"policy"``;
+    ``"spritz"`` stays a top-level alias for pre-refactor callers."""
     state: dict = {}
     for k, v in carry._asdict().items():
         if k == "policy":
@@ -1099,9 +1125,9 @@ def run(spec: SimSpec, seed: int = 0, chunk: int | None = None,
             "ignore", message="Some donated buffers were not usable")
         carry, t, steps = runner(carry0, watch, jnp.int32(t0),
                                  jnp.int32(steps0), jnp.int32(limit))
-    res = _result(carry, t, steps)
+    res = _result(*_fetch_results(carry, t, steps))
     if return_carry:
-        return res, _carry_state(carry)
+        return res, _carry_state(jax.device_get(carry))
     return res
 
 
@@ -1179,16 +1205,23 @@ def run_batch(spec: SimSpec | Sequence[SimSpec],
             span.set_metadata(
                 runner_built=int(runner._cache_size() > n_traced))
         with TraceAnnotation("spritz.results") as span:
+            leaves, t, steps = _fetch_results(carry, t, steps)
+            transfers = len(leaves) + 2
+            if return_carry:
+                carry = jax.device_get(carry)
+                transfers += len(jax.tree.leaves(carry))
             out, states, copied = [], [], 0
             for i in range(n_lanes):  # pad lanes (lane-0 replicas) dropped
-                lane_carry = jax.tree.map(lambda x: x[i], carry)
-                out.append(_result(lane_carry, t[i], steps[i]))
+                # a copy per lane: no lane's arrays alias another's
+                lane = _ResultLeaves(*(np.array(x[i]) for x in leaves))
+                out.append(_result(lane, t[i], steps[i]))
                 copied += sum(v.nbytes for v in out[-1]
                               if isinstance(v, np.ndarray))
                 if return_carry:
+                    lane_carry = jax.tree.map(lambda x: np.array(x[i]), carry)
                     states.append(_carry_state(lane_carry))
                     copied += live_carry_bytes(lane_carry)
-            span.set_metadata(copied_bytes=copied,
+            span.set_metadata(copied_bytes=copied, transfers=transfers,
                               steps=max(r.steps_executed for r in out))
     if return_carry:
         return out, states

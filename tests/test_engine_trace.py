@@ -42,15 +42,21 @@ def test_phase_scopes_reach_the_compiled_program(batched):
     assert scopes == set(E.SCOPES)
 
 
-def _spans(trace_dir: Path) -> list[tuple[int, int, str, dict]]:
-    """(start, end, name, stats) of every ``spritz.*`` host span."""
+def _host_events(trace_dir: Path, prefix: str
+                 ) -> list[tuple[int, int, str, dict]]:
+    """(start, end, name, stats) of every host event named ``prefix*``."""
     path, = Path(trace_dir).rglob("*.xplane.pb")
     data = jax.profiler.ProfileData.from_file(str(path))
-    return sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
-                   dict(ev.stats))
-                  for plane in data.planes if plane.name.startswith("/host:")
-                  for line in plane.lines for ev in line.events
-                  if ev.name.startswith("spritz."))
+    return sorted(((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+                    dict(ev.stats))
+                   for plane in data.planes if plane.name.startswith("/host:")
+                   for line in plane.lines for ev in line.events
+                   if ev.name.startswith(prefix)), key=lambda ev: ev[:3])
+
+
+def _spans(trace_dir: Path) -> list[tuple[int, int, str, dict]]:
+    """(start, end, name, stats) of every ``spritz.*`` host span."""
+    return _host_events(trace_dir, "spritz.")
 
 
 def test_run_batch_spans_in_a_profiler_trace(tmp_path):
@@ -70,7 +76,43 @@ def test_run_batch_spans_in_a_profiler_trace(tmp_path):
                  if lo <= s and e <= hi and n != "spritz.run_batch"}
         assert set(inner) == set(E.SPANS) - {"spritz.run_batch"}
         assert inner["spritz.dispatch"] == {"runner_built": built}
-        # 7 per-flow arrays per lane: six int32 counters and ``done``
+        # 7 per-flow arrays per lane: six int32 counters and ``done``;
+        # 10 arrays fetched: the 8 result leaves, the tick and the steps
         assert inner["spritz.results"] == {
             "copied_bytes": 6 * spec.n_flows * (6 * 4 + 1),
+            "transfers": 10,
             "steps": max(r.steps_executed for r in res)}
+
+
+@pytest.mark.parametrize("n_seeds, return_carry", [(1, False), (6, False),
+                                                  (1, True), (6, True)],
+                         ids=["1lane", "6lanes", "1lane-carry",
+                              "6lanes-carry"])
+def test_results_span_counts_transfers_not_lanes(tmp_path, n_seeds,
+                                                 return_carry):
+    """The results are fetched in a fixed number of transfers, whatever
+    the lane count, and no jitted op runs while they are copied out."""
+    spec = _spec(n_ticks=(1 << 11) + 1)
+    seeds = list(range(n_seeds))
+    E.run_batch(spec, SCHEMES[:1], seeds, until_tick=64)   # warm
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = E.run_batch(spec, SCHEMES[:1], seeds, until_tick=64,
+                          return_carry=return_carry)
+    finally:
+        jax.profiler.stop_trace()
+    res = out[0] if return_carry else out
+    assert len(res) == n_seeds
+    carry = E.init_carry(spec, 0)
+    n_leaves = len(jax.tree.leaves(carry))
+    copied = n_seeds * spec.n_flows * (6 * 4 + 1)
+    if return_carry:
+        copied += n_seeds * E.live_carry_bytes(carry)
+    (lo, hi, _, stats), = [sp for sp in _spans(tmp_path)
+                           if sp[2] == "spritz.results"]
+    assert stats == {"copied_bytes": copied,
+                     "transfers": 10 + n_leaves * return_carry,
+                     "steps": max(r.steps_executed for r in res)}
+    jitted = [n for s, e, n, _ in _host_events(tmp_path, "PjitFunction")
+              if lo <= s and e <= hi]
+    assert jitted == []
